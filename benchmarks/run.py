@@ -29,6 +29,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 from benchmarks import common  # noqa: E402
 from benchmarks.paper_benchmarks import ALL_BENCHMARKS  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 QUICK_BENCHMARKS = ("fig8_device_tier_batched", "multi_grade_round",
                     "round_pipeline", "million_device_round",
@@ -101,6 +102,7 @@ def main(argv=None) -> int:
                     help="persist rows to a JSON artifact and diff tracked "
                          "metrics against the newest BENCH_PR*.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     common.QUICK = args.quick
 
     print("name,us_per_call,derived")

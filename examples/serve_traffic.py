@@ -8,5 +8,5 @@ import sys
 
 from repro.launch.serve import main
 
-sys.exit(main(["--arch", "llama3_2_3b", "--requests", "32",
+sys.exit(main(["--arch", "llama3_2_3b", "--smoke", "--requests", "32",
                "--batch-size", "4", "--sigma", "1.0"]))

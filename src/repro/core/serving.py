@@ -6,8 +6,9 @@ batch fires waits a full batch-fill interval, and off-peak traffic strands
 sub-batch residuals.  Continuous batching decouples them (ROADMAP item 2):
 
 * ``init_arena`` allocates a fixed-capacity KV-cache *arena* — per layer
-  ``(slots, max_len, kv, head_dim)`` — plus one per-slot ``lengths`` counter.
-  A slot IS a request's cache residency for its whole lifetime.
+  ``(slots, kv, max_len, head_dim)``, head-major so the decode kernel's K/V
+  tiles are contiguous — plus one per-slot ``lengths`` counter.  A slot IS a
+  request's cache residency for its whole lifetime.
 * ``arena_prefill`` runs the full-sequence forward for newly admitted
   prompts and scatters their K/V rows into freed slots.  The call is padded
   to a single static shape; out-of-bounds slot ids mark padding rows whose
@@ -35,6 +36,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import heapq
 from typing import Any, Callable
 
@@ -70,6 +72,7 @@ __all__ = [
     "ServingReport",
     "ContinuousBatchingEngine",
     "ContinuousServer",
+    "init_params",
     "init_arena",
     "arena_prefill",
     "arena_decode",
@@ -195,15 +198,24 @@ class ServingReport:
 
 
 # --------------------------------------------------------------------------- #
-# KV arena + fused jitted arena ops
+# Weights, KV arena + fused jitted arena ops
 # --------------------------------------------------------------------------- #
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def init_params(cfg: ModelConfig, seed: int) -> Any:
+    """A model's random weights from ``seed``, built in one jit so only the
+    final (bf16) arrays materialize: an eager init of a published-width MoE
+    would also hold each expert stack's f32 RNG temporaries on the device."""
+    return get_model(cfg).init(jax.random.PRNGKey(seed), cfg)
+
+
 def init_arena(cfg: ModelConfig, slots: int, max_len: int) -> dict:
-    """Fixed-capacity KV arena: per-layer ``(slots, max_len, kv, hd)`` caches
-    plus one per-slot ``lengths`` counter (0 = empty/retired slot)."""
+    """Fixed-capacity KV arena: per-layer head-major ``(slots, kv, max_len,
+    hd)`` caches plus one per-slot ``lengths`` counter (0 = empty/retired
+    slot)."""
     dt = jnp.dtype(cfg.dtype)
 
     def one():
-        shape = (slots, max_len, cfg.num_kv_heads, cfg.head_dim)
+        shape = (slots, cfg.num_kv_heads, max_len, cfg.head_dim)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
     if cfg.scan_layers:
@@ -289,7 +301,7 @@ def arena_decode(params, tok: jax.Array, active: jax.Array, arena: dict,
     slots = tok.shape[0]
     lengths = arena["lengths"]
     kv = arena["kv"]
-    max_len = (kv["k"].shape[2] if cfg.scan_layers else kv[0]["k"].shape[1])
+    max_len = (kv["k"].shape[3] if cfg.scan_layers else kv[0]["k"].shape[2])
     if block_k is None:
         block_k = tuned_block_k(max_len, head_dim=cfg.head_dim)
     x = embed_apply(params["embed"], tok[:, None])  # (slots, 1, d)
@@ -356,7 +368,7 @@ class ContinuousBatchingEngine:
             if api.prefill is None or api.decode_step is None:
                 raise ValueError(f"family {cfg.family!r} has no serving path")
             self.params = (params if params is not None
-                           else api.init(jax.random.PRNGKey(seed), cfg))
+                           else init_params(cfg, seed))
             self.arena = init_arena(cfg, slots, self.max_len)
             self._tok = jnp.zeros((slots,), jnp.int32)
             self._prefill = jax.jit(

@@ -101,15 +101,14 @@ def _stack_params(params: Params, n: int) -> Params:
 def _shard_over_data(fn, mesh, data_axis: str, n_in: int, n_out: int):
     """Wrap a vmapped fn so every arg/output shards over the mesh data axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     spec = P(data_axis)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec,) * n_in,
         out_specs=(spec,) * n_out if n_out > 1 else spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

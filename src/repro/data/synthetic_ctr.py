@@ -40,19 +40,22 @@ class CTRDataset:
         """Fixed-size per-device batches (pad/trim) for vectorized simulation.
 
         Returns (features (D, R, dim), labels (D, R), num_samples (D,)).
+        Each device keeps its first ``R`` records in dataset order; one
+        vectorized gather serves the paper's 100 000-device fleet.
         """
-        n = len(device_ids)
-        X = np.zeros((n, records_per_device, self.dim), np.float32)
-        Y = np.zeros((n, records_per_device), np.float32)
-        counts = np.zeros((n,), np.int32)
-        for i, d in enumerate(device_ids):
-            x, y = self.device_shard(int(d))
-            k = min(len(x), records_per_device)
-            if k == 0:
-                continue
-            X[i, :k] = x[:k]
-            Y[i, :k] = y[:k]
-            counts[i] = k
+        device_ids = np.asarray(device_ids, np.int64)
+        R = records_per_device
+        order = np.argsort(self.device_ids, kind="stable")
+        sorted_ids = self.device_ids[order]
+        start = np.searchsorted(sorted_ids, device_ids, side="left")
+        end = np.searchsorted(sorted_ids, device_ids, side="right")
+        counts = np.minimum(end - start, R).astype(np.int32)
+        valid = np.arange(R)[None, :] < counts[:, None]  # (D, R)
+        src = order[(start[:, None] + np.arange(R)[None, :])[valid]]
+        X = np.zeros((len(device_ids), R, self.dim), np.float32)
+        Y = np.zeros((len(device_ids), R), np.float32)
+        X[valid] = self.features[src]
+        Y[valid] = self.labels[src]
         return X, Y, counts
 
 
@@ -89,10 +92,13 @@ def make_federated_ctr(
         dev_seg_probs = np.full((num_devices, n_segments), 1.0 / n_segments)
 
     device_ids = np.repeat(np.arange(num_devices, dtype=np.int32), records_per_device)
-    seg = np.array(
-        [rng.choice(n_segments, p=dev_seg_probs[d]) for d in device_ids],
-        dtype=np.int32,
-    )
+    # One segment draw per record from its device's distribution: the
+    # inverse-CDF lookup ``Generator.choice(p=...)`` makes per call, with the
+    # same uniforms in the same order, done for all records at once.
+    cdf = np.cumsum(dev_seg_probs, axis=1)
+    cdf /= cdf[:, -1:]
+    u = rng.random(n)
+    seg = (cdf[device_ids] <= u[:, None]).sum(axis=1).astype(np.int32)
 
     # Raw categorical values: segment preference + noise, then feature-hashed.
     raw = seg_field_prefs[seg] + rng.integers(0, 50, size=(n, _N_RAW_FIELDS))

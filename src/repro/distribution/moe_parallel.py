@@ -18,7 +18,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -130,12 +129,12 @@ def make_moe_sharded(cfg: ModelConfig, lmesh: LogicalMesh, *, train: bool,
                               ((sp,) if sp else ()))
     fn = functools.partial(_local_moe, cfg=cfg, ep_axis=ep_axis,
                            fsdp_axis=fsdp_axis, avg_axes=avg_axes)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(x_spec, router_spec, wgu_spec, wgu_spec, wd_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def moe_apply_sharded(p: Any, x: jax.Array, cfg_: ModelConfig):

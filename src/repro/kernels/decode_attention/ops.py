@@ -1,8 +1,14 @@
 """Public entry point for decode attention (single-token, KV cache).
 
+``decode_attention`` takes **head-major** caches ``(b, kv, s, d)`` — the
+layout of the continuous-batching KV arena (``core.serving``), chosen so the
+Pallas kernel's K/V tiles are contiguous ``(block_k, d)`` slabs.  The jnp
+reference ``decode_attention_ref`` keeps the sequence-major ``(b, s, kv, d)``
+layout of the fixed-batch model caches; the ``"ref"`` impl transposes.
+
 Besides the attention op itself this module carries the KV-*arena* slot
-paths used by continuous batching (``core.serving``): a fixed-capacity cache
-of shape ``(slots, max_len, kv, d)`` where each row is one request's cache
+paths used by continuous batching: a fixed-capacity cache of shape
+``(slots, kv, max_len, d)`` where each slot row is one request's cache
 residency.  Slot writes use out-of-bounds indices as padding sentinels
 (``mode="drop"``), so the jitted update has one static shape regardless of
 how many requests were admitted this iteration.
@@ -14,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.decode_attention.decode_attention import decode_attention_pallas
 from repro.kernels.decode_attention.ref import (
     combine_partials,
@@ -33,14 +40,10 @@ __all__ = [
 ]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("scale", "block_k", "impl"))
 def decode_attention(
     q: jax.Array,  # (b, h, d)
-    k_cache: jax.Array,  # (b, s, kv, d)
+    k_cache: jax.Array,  # (b, kv, s, d) — head-major
     v_cache: jax.Array,
     lengths: jax.Array,  # (b,)
     *,
@@ -49,19 +52,16 @@ def decode_attention(
     impl: str = "auto",
 ) -> jax.Array:
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
-    if impl == "pallas":
+        impl = "pallas" if kernels.on_tpu() else "ref"
+    if impl in ("pallas", "pallas_interpret"):
         return decode_attention_pallas(
             q, k_cache, v_cache, lengths, scale=scale, block_k=block_k,
-            interpret=not _on_tpu(),
-        )
-    if impl == "pallas_interpret":
-        return decode_attention_pallas(
-            q, k_cache, v_cache, lengths, scale=scale, block_k=block_k,
-            interpret=True,
+            interpret=kernels.pallas_interpret(impl),
         )
     if impl == "ref":
-        return decode_attention_ref(q, k_cache, v_cache, lengths, scale=scale)
+        return decode_attention_ref(
+            q, k_cache.swapaxes(1, 2), v_cache.swapaxes(1, 2), lengths,
+            scale=scale)
     raise ValueError(f"unknown impl {impl!r}")
 
 
@@ -72,32 +72,33 @@ def scatter_prefill_rows(cache: jax.Array, rows: jax.Array,
                          slot_ids: jax.Array) -> jax.Array:
     """Write freshly prefilled K/V rows into their arena slots.
 
-    ``cache`` is ``(slots, max_len, kv, d)``; ``rows`` is ``(m, s, kv, d)``
-    with ``s <= max_len``; ``slot_ids`` is ``(m,) int32``.  Entries with
+    ``cache`` is ``(slots, kv, max_len, d)``; ``rows`` is ``(m, s, kv, d)``
+    (the projection's sequence-major layout) with ``s <= max_len``;
+    ``slot_ids`` is ``(m,) int32``.  Entries with
     ``slot_ids[i] >= slots`` are padding — their writes drop, so a single
     jitted shape serves any number of admissions.  Rows ``[s:max_len)`` of a
     reused slot keep the previous occupant's stale K/V; they are dead by
     construction because the slot's length counter is reset to ``s``.
     """
     s = rows.shape[1]
-    return cache.at[slot_ids, :s].set(rows, mode="drop")
+    return cache.at[slot_ids, :, :s].set(rows.swapaxes(1, 2), mode="drop")
 
 
 def scatter_decode_token(cache: jax.Array, kv_tok: jax.Array,
                          write_pos: jax.Array) -> jax.Array:
     """Write one decoded token's K/V at each slot's own cache position.
 
-    ``cache`` is ``(slots, max_len, kv, d)``; ``kv_tok`` is ``(slots, kv, d)``;
+    ``cache`` is ``(slots, kv, max_len, d)``; ``kv_tok`` is ``(slots, kv, d)``;
     ``write_pos`` is ``(slots,) int32`` — per-slot ragged positions.  Inactive
     slots pass ``write_pos >= max_len`` and their writes drop.
     """
     slots = cache.shape[0]
-    return cache.at[jnp.arange(slots, dtype=jnp.int32), write_pos].set(
+    return cache.at[jnp.arange(slots, dtype=jnp.int32), :, write_pos].set(
         kv_tok, mode="drop")
 
 
 def gather_slots(cache: jax.Array, slot_ids: jax.Array) -> jax.Array:
-    """Gather ``(m, max_len, kv, d)`` slot rows (e.g. to migrate or inspect a
+    """Gather ``(m, kv, max_len, d)`` slot rows (e.g. to migrate or inspect a
     request's cache residency); out-of-bounds ids fill with zeros."""
     return cache.at[slot_ids].get(mode="fill", fill_value=0)
 

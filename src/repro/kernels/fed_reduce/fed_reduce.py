@@ -47,8 +47,11 @@ def _fed_reduce_kernel(w_ref, x_ref, o_ref):
 
     w = w_ref[...].astype(jnp.float32)  # (1, block_n)
     x = x_ref[...].astype(jnp.float32)  # (block_n, block_d)
+    # HIGHEST: the MXU's default single bf16 pass would round the f32 weights
+    # and updates to 8 significant bits; the reduction must stay f32-exact.
     o_ref[...] += jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

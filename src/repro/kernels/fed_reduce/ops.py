@@ -7,7 +7,8 @@ which maps it over every ``(rows, size)`` leaf of an ``UpdateBuffer``).
 
 Implementations:
 
-* ``pallas`` — the TPU kernel (MXU matmul accumulation, f32);
+* ``pallas`` — the compiled TPU kernel (MXU matmul accumulation, f32);
+  raises off a TPU;
 * ``pallas_interpret`` — the same kernel under the Pallas interpreter, the
   CPU-CI correctness path;
 * ``ref`` — fused jnp ``tensordot`` (also the fast CPU execution path);
@@ -39,9 +40,9 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import kernels
 from repro.analysis.sanitizers import hot_path
 from repro.kernels.fed_reduce.fed_reduce import fed_reduce_pallas
 from repro.kernels.fed_reduce.ref import fed_reduce_ref
@@ -53,10 +54,21 @@ __all__ = ["fed_reduce", "fed_reduce_ref", "tuned_blocks"]
 # floor everywhere.
 _MIN_BLOCK_N = 32
 _MIN_BLOCK_D = 128
+# The kernel's weight operand is a (1, rows) row vector tiled (1, block_n):
+# once the rows span several blocks, block_n is its lane dimension and must
+# be a multiple of 128.  A multiple of 128 is also a multiple of every wire
+# dtype's sublane tile (8 f32, 16 bf16, 32 int8), so one rule covers both
+# operands for any stack an override may meet.
+_LANE = 128
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _check_blocks(block_n: int, block_d: int) -> tuple[int, int]:
+    """Reject ``(block_n, block_d)`` pairs the TPU tiling rule refuses."""
+    if block_n < 1 or block_d < 1 or block_n % _LANE or block_d % _LANE:
+        raise ValueError(
+            f"fed_reduce blocks ({block_n}, {block_d}) break the TPU tiling: "
+            f"block_n and block_d must be positive multiples of {_LANE}")
+    return block_n, block_d
 
 
 def tuned_blocks(rows: int, size: int, dtype,
@@ -77,7 +89,8 @@ def tuned_blocks(rows: int, size: int, dtype,
     padding rows/columns 8x past the data.
 
     ``FED_REDUCE_BLOCKS="<block_n>,<block_d>"`` in the environment overrides
-    the table outright (bench sweeps, regression pinning).
+    the table outright (bench sweeps, regression pinning); both must be
+    multiples of 128, or it raises.
     """
     override = os.environ.get("FED_REDUCE_BLOCKS")
     if override:
@@ -87,7 +100,7 @@ def tuned_blocks(rows: int, size: int, dtype,
             raise ValueError(
                 f"FED_REDUCE_BLOCKS must be 'block_n,block_d', "
                 f"got {override!r}") from None
-        return bn, bd
+        return _check_blocks(bn, bd)
     if rows < 1 or size < 1:
         raise ValueError(f"need rows, size >= 1, got ({rows}, {size})")
     itemsize = jnp.dtype(dtype).itemsize
@@ -119,7 +132,7 @@ def _fed_reduce_local(stack: jax.Array, weights: jax.Array,
         bn, bd = tuned_blocks(n, flat.shape[1], stack.dtype)
         out = fed_reduce_pallas(
             flat, weights, block_n=bn, block_d=bd,
-            interpret=(impl == "pallas_interpret" or not _on_tpu()))
+            interpret=kernels.pallas_interpret(impl))
         return out.reshape(stack.shape[1:])
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -160,7 +173,7 @@ def fed_reduce(stack: jax.Array, weights: jax.Array, *,
         # vector; a zero weight still zeroes the whole row.
         weights = weights.astype(jnp.float32) * scales.astype(jnp.float32)
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+        impl = "pallas" if kernels.on_tpu() else "ref"
     if mesh is None:
         return _fed_reduce_local(stack, weights, impl)
     if axis not in mesh.axis_names:
@@ -188,6 +201,9 @@ def fed_reduce(stack: jax.Array, weights: jax.Array, *,
     def _shard_reduce(s, w):
         return jax.lax.psum(_fed_reduce_local(s, w, impl), axis)
 
-    return shard_map(
+    # check_vma=False: a pallas_call's output carries no varying-axes
+    # annotation, which the checker requires; the psum makes it replicated.
+    return jax.shard_map(
         _shard_reduce, mesh=mesh, in_specs=(row_spec, P(axis)),
-        out_specs=P(*([None] * (stack.ndim - 1))))(stack, weights)
+        out_specs=P(*([None] * (stack.ndim - 1))),
+        check_vma=False)(stack, weights)

@@ -1,16 +1,22 @@
 """Pallas TPU flash attention (GQA, causal) — prefill/training kernel.
 
 TPU adaptation notes (vs the CUDA FlashAttention algorithm):
+* the kernel runs on **head-major** operands: the wrapper transposes q to
+  ``(batch, heads, seq, head_dim)`` and k/v to ``(batch, kv_heads, seq,
+  head_dim)`` so every tile is a contiguous ``(block, head_dim)`` slab whose
+  last two dimensions satisfy the chip's (8, 128) tiling rule — a
+  ``(1, head_dim)`` head slice of a sequence-major array is refused by the
+  TPU compiler;
 * the grid is ``(batch, q_heads, num_q_blocks, num_kv_blocks)`` with the KV
   block dimension innermost — TPU grids execute sequentially over the last
-  axis, so the online-softmax running state (m, l, acc) lives in **VMEM
+  axis, so the online-softmax running state (m, l, acc) lives in 2-D **VMEM
   scratch** that persists across KV steps (no atomics / shared-memory
   reductions as on GPU);
 * block shapes are MXU-aligned: ``block_q x head_dim`` and
   ``block_k x head_dim`` tiles feed the 128x128 systolic array directly;
 * GQA is expressed in the BlockSpec ``index_map`` — the kv-head index is
   ``q_head // group_size``, so no materialized ``repeat`` of K/V ever leaves
-  HBM (the XLA baseline pays that cost; see EXPERIMENTS.md §Perf).
+  HBM.
 
 VMEM budget per grid step (bf16 inputs, f32 scratch):
 ``block_q*d*2 + 2*block_k*d*2 + block_q*block_k*4 (transient) +
@@ -54,9 +60,9 @@ def _flash_kernel(
 
     @pl.when(should_compute)
     def _compute():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[...]  # (block_q, d)
+        k = k_ref[...]  # (block_k, d)
+        v = v_ref[...]
         s = jax.lax.dot_general(
             (q * scale).astype(q.dtype), k,
             (((1,), (1,)), ((), ())),
@@ -70,12 +76,12 @@ def _flash_kernel(
             )
             valid &= qpos >= kpos
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]  # (block_q, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -84,7 +90,7 @@ def _flash_kernel(
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -109,11 +115,13 @@ def flash_attention_pallas(
     nq = -(-sq // block_q)
     nk = -(-sk // block_k)
     sq_p, sk_p = nq * block_q, nk * block_k
-    if sq_p != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
-    if sk_p != sk:
-        k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
+    # Head-major operands (module docstring), padded to block multiples.
+    q = jnp.pad(q.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
+    k = jnp.pad(k.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+    v = jnp.pad(v.transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
 
     kernel = functools.partial(
         _flash_kernel,
@@ -125,27 +133,21 @@ def flash_attention_pallas(
         q_offset=q_offset,
         kv_len=sk,
     )
+    q_block = pl.BlockSpec((None, None, block_q, d),
+                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_block = pl.BlockSpec((None, None, block_k, d),
+                            lambda bi, hi, qi, ki: (bi, hi // g, ki, 0))
     out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec(
-                (1, block_k, 1, d), lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, 1, d), lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, 1, d), lambda bi, hi, qi, ki: (bi, qi, hi, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, d), q.dtype),
+        in_specs=[q_block, kv_block, kv_block],
+        out_specs=q_block,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return out[:, :, :sq].transpose(0, 2, 1, 3)

@@ -1,9 +1,10 @@
 """Public entry point for flash attention.
 
-Dispatch: Pallas kernel on TPU backends (or when ``interpret`` is forced for
-validation), lowerable chunked-jnp implementation elsewhere (CPU dry-runs,
-grad support).  The chunked implementation is the same online-softmax math,
-so the two paths are interchangeable bit-for-tolerance (tests enforce this).
+Dispatch: the compiled Pallas kernel on TPU backends (``impl="pallas"``
+raises elsewhere; ``"pallas_interpret"`` interprets it for validation), the
+lowerable chunked-jnp implementation elsewhere (CPU dry-runs, grad
+support).  The chunked implementation is the same online-softmax math, so
+the two paths are interchangeable bit-for-tolerance (tests enforce this).
 """
 from __future__ import annotations
 
@@ -12,12 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_chunked, attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -40,16 +38,12 @@ def flash_attention(
 ) -> jax.Array:
     """Multi-head/GQA attention: q (b,sq,h,d), k/v (b,sk,kv,d) -> (b,sq,h,d)."""
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "chunked"
-    if impl == "pallas":
+        impl = "pallas" if kernels.on_tpu() else "chunked"
+    if impl in ("pallas", "pallas_interpret"):
         return flash_attention_pallas(
             q, k, v, causal=causal, q_offset=q_offset, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=not _on_tpu(),
-        )
-    if impl == "pallas_interpret":
-        return flash_attention_pallas(
-            q, k, v, causal=causal, q_offset=q_offset, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=True,
+            block_q=block_q, block_k=block_k,
+            interpret=kernels.pallas_interpret(impl),
         )
     if impl == "chunked":
         # No q-chunking on the lowerable path: a python loop of static

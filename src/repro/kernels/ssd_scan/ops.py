@@ -5,14 +5,11 @@ import functools
 
 import jax
 
+from repro import kernels
 from repro.kernels.ssd_scan.ref import ssd_chunked, ssd_decode_step, ssd_ref
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 
 __all__ = ["ssd_scan", "ssd_decode_step", "ssd_ref", "ssd_chunked"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "impl"))
@@ -28,7 +25,7 @@ def ssd_scan(
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (y (b,l,h,p), final_state (b,h,p,n))."""
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "chunked"
+        impl = "pallas" if kernels.on_tpu() else "chunked"
     l = x.shape[1]
     chunk = min(chunk, l)
     if l % chunk:
@@ -39,10 +36,9 @@ def ssd_scan(
         y, s = ssd_scan(padt(x), padt(dt), A, padt(B), padt(C),
                         chunk=chunk, impl=impl)
         return y[:, :l], s
-    if impl == "pallas":
-        return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk, interpret=not _on_tpu())
-    if impl == "pallas_interpret":
-        return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk, interpret=True)
+    if impl in ("pallas", "pallas_interpret"):
+        return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
+                               interpret=kernels.pallas_interpret(impl))
     if impl == "chunked":
         return ssd_chunked(x, dt, A, B, C, chunk=chunk)
     if impl == "ref":

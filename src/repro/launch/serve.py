@@ -49,11 +49,13 @@ from repro.core.serving import (
     RequestRecord,
     ServeCostModel,
     ServingReport,
+    init_params,
 )
 from repro.core.strategies import TimeIntervalStrategy
 from repro.core.task import GradeSpec, OperatorFlow, Task
 from repro.core.traffic_curves import diurnal, right_tailed_normal
 from repro.core.updates import UpdateBuffer, UpdateHandle
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_model
 
 
@@ -89,7 +91,7 @@ class BatchedServer:
                  cost_model: ServeCostModel | None = None, fused: bool = True):
         self.cfg = cfg
         self.api = get_model(cfg)
-        self.params = self.api.init(jax.random.PRNGKey(seed), cfg)
+        self.params = init_params(cfg, seed)
         self.batch_size = batch_size
         self.prompt_len = prompt_len
         self.decode_tokens = decode_tokens
@@ -267,7 +269,7 @@ def print_report(name: str, rep: ServingReport, slo_s: float) -> None:
           f"(SLO {slo_s * 1e3:.0f}ms attained {s['slo_attainment'] * 100:.1f}%)")
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3_2_3b")
     ap.add_argument("--mode", choices=("fixed", "continuous", "both"),
@@ -290,10 +292,21 @@ def main(argv=None):
     ap.add_argument("--co-train", action="store_true",
                     help="run the serve-over-train preemption schedule at "
                          "the curve peak")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the architecture's reduced smoke preset "
+                         "instead of its published widths")
+    ap.add_argument("--attn-impl", default="auto",
+                    choices=("auto", "pallas", "pallas_interpret", "ref"),
+                    help="continuous mode's decode attention "
+                         "(kernels.decode_attention impl)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=True)
+
+def run(args: argparse.Namespace) -> dict[str, ServingReport]:
+    """Serve ``args``' trace in the requested modes; returns each mode's
+    report (its records carry every request's decoded tokens)."""
+    cfg = get_config(args.arch, smoke=args.smoke)
     max_len = args.prompt_len + args.decode_tokens + 1
     curve = (diurnal() if args.curve == "diurnal"
              else right_tailed_normal(args.sigma))
@@ -320,7 +333,7 @@ def main(argv=None):
         engine = ContinuousBatchingEngine(
             cfg, slots=args.batch_size, prompt_len=args.prompt_len,
             decode_tokens=args.decode_tokens, max_len=max_len,
-            seed=args.seed, cost_model=cost)
+            seed=args.seed, cost_model=cost, attn_impl=args.attn_impl)
         clock = VirtualClock()
         server = ContinuousServer(engine, clock)
         run_trace(server, requests=args.requests,
@@ -353,6 +366,12 @@ def main(argv=None):
               f"queued {burst_ex.queueing_delay_s:.1f}s; training preempted "
               f"{train_ex.preemptions}x, decisions "
               f"{train_ex.preemption_decisions}")
+    return reports
+
+
+def main(argv=None):
+    enable_compile_cache()
+    run(parse_args(argv))
     return 0
 
 

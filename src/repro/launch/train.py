@@ -51,6 +51,7 @@ from repro.core.updates import UpdateBuffer, UpdateHandle
 from repro.data.tokens import TokenPipeline
 from repro.distribution.sharding import derive_logical_mesh, make_fleet_mesh
 from repro.distribution.steps import build_train_step, init_train_state
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import get_model
 from repro.optim.compression import (
@@ -501,6 +502,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "cloud":
         out = cloud_training(args)
     elif args.tasks > 1:

@@ -89,7 +89,9 @@ def init(key, cfg: ModelConfig) -> Params:
     }
     layer_keys = jax.random.split(kl, cfg.num_layers)
     if cfg.scan_layers:
-        params["layers"] = jax.vmap(lambda k: layer_init(k, cfg))(layer_keys)
+        # One layer at a time: a vmap would hold every layer's f32 RNG
+        # temporaries at once (tens of GB for a published-width MoE).
+        params["layers"] = jax.lax.map(lambda k: layer_init(k, cfg), layer_keys)
     else:
         params["layers"] = [layer_init(k, cfg) for k in layer_keys]
     return params

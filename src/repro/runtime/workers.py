@@ -61,6 +61,7 @@ selects this path; see ``examples/quickstart.py`` §11.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -145,13 +146,12 @@ class WorkerSpec:
     by reference) returning ``(logical_tier, {grade: device_tier})`` built
     exactly like the coordinator's tiers — same local_train, dtypes, and
     cohort sizes — so worker-computed chunks are bit-identical to inline
-    ones.  ``env`` entries are applied to ``os.environ`` before JAX
-    initializes in the child (e.g. to pin XLA host threads per worker).
+    ones (on the same backend; workers run on the CPU, see
+    :class:`FleetWorkerPool`).
     """
 
     factory: Callable[..., tuple]
     kwargs: Mapping[str, Any] = dataclasses.field(default_factory=dict)
-    env: Mapping[str, str] = dataclasses.field(default_factory=dict)
 
     def build(self) -> tuple:
         logical, tiers = self.factory(**dict(self.kwargs))
@@ -323,7 +323,6 @@ class _WorkerState:
 
 def _worker_main(worker_id: int, conn, spec: WorkerSpec,
                  delay_s: float) -> None:
-    os.environ.update(dict(spec.env))
     state = _WorkerState(worker_id, spec, delay_s)
     try:
         conn.send(("ready", worker_id))
@@ -367,6 +366,23 @@ class _Seg:
     owner: int
 
 
+@contextlib.contextmanager
+def _environ(overrides: Mapping[str, str]):
+    """Set ``overrides`` in ``os.environ`` for the block, then restore.  A
+    spawned child copies the environment when it is started, so this is how
+    a worker gets variables JAX reads while it is imported."""
+    saved = {k: os.environ.get(k) for k in overrides}
+    os.environ.update(overrides)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 class FleetWorkerPool:
     """Coordinator handle on N spawned cohort workers.
 
@@ -374,6 +390,15 @@ class FleetWorkerPool:
     forking an initialized JAX runtime is unsafe) and are daemons, so a
     crashed coordinator never strands them.  See the module docstring for
     the transport/recycling/fault model.
+
+    Workers run the fleet on the host CPUs (the host-CPU fleet design): the
+    pool starts every child with ``JAX_PLATFORMS=cpu``, whatever the
+    coordinator's environment says.  An accelerator belongs to one process
+    at a time, and on a TPU host the coordinator holds it (aggregation and
+    serving run there), so a child that tried to initialise the TPU would
+    fail or hang.  Pooled chunks therefore run on the CPU backend; they are
+    bit-identical to inline rounds when the coordinator's backend is the CPU
+    too.
     """
 
     def __init__(self, spec: WorkerSpec, num_workers: int, *,
@@ -416,7 +441,8 @@ class FleetWorkerPool:
             proc = ctx.Process(target=_worker_main,
                                args=(wid, child, self.spec, delay),
                                daemon=True, name=f"fleet-worker-{wid}")
-            proc.start()
+            with _environ({"JAX_PLATFORMS": "cpu"}):
+                proc.start()
             child.close()
             self._workers.append(_WorkerHandle(wid, proc, parent))
         deadline = time.monotonic() + self.start_timeout_s

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import on_tpu
 from repro.kernels.decode_attention.ops import (
     combine_partials,
     decode_attention,
@@ -81,7 +82,9 @@ def test_decode_attention_matches_oracle(case, dtype):
     ref = decode_attention_ref(
         q.astype(jnp.float32), kc.astype(jnp.float32),
         vc.astype(jnp.float32), lens)
-    out = decode_attention(q, kc, vc, lens, impl="pallas_interpret")
+    # The kernel reads head-major (b, kv, s, d) caches.
+    out = decode_attention(q, kc.swapaxes(1, 2), vc.swapaxes(1, 2), lens,
+                           impl="pallas_interpret")
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=tol(dtype), rtol=tol(dtype))
@@ -114,14 +117,24 @@ def test_decode_partial_combine_equals_full():
 DECODE_IMPLS = ["pallas", "pallas_interpret", "ref"]
 
 
+def _pallas_refused_off_tpu(impl, q, kc, vc, lens) -> bool:
+    """``impl="pallas"`` is the compiled kernel: off a TPU it must raise, not
+    fall back to the interpreter.  True when that case was checked here."""
+    if impl != "pallas" or on_tpu():
+        return False
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        decode_attention(q, kc, vc, lens, impl=impl, block_k=16)
+    return True
+
+
 @pytest.mark.parametrize("impl", DECODE_IMPLS)
 def test_decode_attention_slot_reuse_ignores_stale_kv(impl):
     """Retire a slot mid-stream, prefill a shorter request into it, and
     assert attention NEVER reads the retired request's stale KV rows: the
     reused (dirty) arena must attend identically to a zero-scrubbed one."""
     slots, s, h, kv, d = 4, 96, 8, 2, 32
-    old_k = jnp.asarray(RNG.standard_normal((slots, s, kv, d)), jnp.float32)
-    old_v = jnp.asarray(RNG.standard_normal((slots, s, kv, d)), jnp.float32)
+    old_k = jnp.asarray(RNG.standard_normal((slots, kv, s, d)), jnp.float32)
+    old_v = jnp.asarray(RNG.standard_normal((slots, kv, s, d)), jnp.float32)
     # Slot 2 retires; a new 24-token request prefills into its rows [0:24).
     new_len = 24
     rows_k = jnp.asarray(RNG.standard_normal((1, new_len, kv, d)), jnp.float32)
@@ -129,12 +142,14 @@ def test_decode_attention_slot_reuse_ignores_stale_kv(impl):
     sid = jnp.asarray([2], jnp.int32)
     dirty_k = scatter_prefill_rows(old_k, rows_k, sid)
     dirty_v = scatter_prefill_rows(old_v, rows_v, sid)
-    clean_k = dirty_k.at[2, new_len:].set(0.0)
-    clean_v = dirty_v.at[2, new_len:].set(0.0)
+    clean_k = dirty_k.at[2, :, new_len:].set(0.0)
+    clean_v = dirty_v.at[2, :, new_len:].set(0.0)
     # Stale rows really are still there (reuse, not a wipe) ...
-    assert np.abs(np.asarray(dirty_k[2, new_len:])).max() > 0
+    assert np.abs(np.asarray(dirty_k[2, :, new_len:])).max() > 0
     lens = jnp.asarray([s, 13, new_len, s], jnp.int32)
     q = jnp.asarray(RNG.standard_normal((slots, h, d)), jnp.float32)
+    if _pallas_refused_off_tpu(impl, q, dirty_k, dirty_v, lens):
+        return
     for block_k in (16, 64, 512):
         out_dirty = decode_attention(q, dirty_k, dirty_v, lens,
                                      impl=impl, block_k=block_k)
@@ -150,10 +165,12 @@ def test_decode_attention_zero_length_slot_outputs_zero(impl):
     """A retired / never-filled slot (length 0) must return exact zeros in
     every impl — not the degenerate uniform average over garbage."""
     slots, s, h, kv, d = 3, 64, 4, 2, 16
-    kc = jnp.asarray(RNG.standard_normal((slots, s, kv, d)), jnp.float32)
-    vc = jnp.asarray(RNG.standard_normal((slots, s, kv, d)), jnp.float32)
+    kc = jnp.asarray(RNG.standard_normal((slots, kv, s, d)), jnp.float32)
+    vc = jnp.asarray(RNG.standard_normal((slots, kv, s, d)), jnp.float32)
     q = jnp.asarray(RNG.standard_normal((slots, h, d)), jnp.float32)
     lens = jnp.asarray([0, 5, 0], jnp.int32)
+    if _pallas_refused_off_tpu(impl, q, kc, vc, lens):
+        return
     out = np.asarray(decode_attention(q, kc, vc, lens, impl=impl, block_k=16))
     assert (out[0] == 0).all() and (out[2] == 0).all()
     assert np.abs(out[1]).max() > 0
@@ -162,14 +179,16 @@ def test_decode_attention_zero_length_slot_outputs_zero(impl):
 def test_scatter_slot_helpers_drop_padding():
     """Out-of-bounds slot ids / write positions are padding sentinels: their
     writes drop, real slots are untouched."""
-    cache = jnp.zeros((3, 8, 2, 4))
-    rows = jnp.ones((2, 5, 2, 4))
+    cache = jnp.zeros((3, 2, 8, 4))  # head-major (slots, kv, max_len, d)
+    rows = jnp.ones((2, 5, 2, 4))  # (m, s, kv, d) from the projection
     out = scatter_prefill_rows(cache, rows, jnp.asarray([1, 3], jnp.int32))
-    assert (np.asarray(out[1, :5]) == 1).all()
+    assert (np.asarray(out[1, :, :5]) == 1).all()
+    assert (np.asarray(out[1, :, 5:]) == 0).all()
     assert (np.asarray(out[0]) == 0).all() and (np.asarray(out[2]) == 0).all()
     tok = jnp.full((3, 2, 4), 7.0)
     out2 = scatter_decode_token(out, tok, jnp.asarray([5, 8, 0], jnp.int32))
-    assert float(out2[0, 5, 0, 0]) == 7.0 and float(out2[2, 0, 0, 0]) == 7.0
+    assert (np.asarray(out2[0, :, 5]) == 7.0).all()
+    assert (np.asarray(out2[2, :, 0]) == 7.0).all()
     assert (np.asarray(out2[1]) == np.asarray(out[1])).all()  # OOB dropped
 
 

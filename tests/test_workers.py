@@ -152,6 +152,23 @@ def test_pool_round_bit_identical(wire, workers, delay):
     assert st["redispatched_chunks"] == 0 and got["failures"] == []
 
 
+def test_pool_workers_never_take_the_accelerator(monkeypatch):
+    """Workers start with JAX_PLATFORMS=cpu whatever the coordinator's
+    environment says: on a TPU host the coordinator holds the chip, and a
+    child that tried to initialise it would fail.  Here the coordinator's
+    environment names a TPU a CPU-only host does not have, so a child that
+    inherited it would die at start; the pooled round still completes
+    bit-identical to the inline one."""
+    import os
+
+    ref = _inline_ref("f32")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    got = _run_world("f32", 2)
+    _assert_equivalent(ref, got)
+    assert got["failures"] == []
+    assert os.environ["JAX_PLATFORMS"] == "tpu"  # coordinator env restored
+
+
 def test_pool_segment_ring_recycles():
     """Round 2 reuses round 1's shared-memory segments (the donation-style
     ring): segment creations stay bounded while reuses accrue."""
@@ -203,23 +220,29 @@ def test_tuned_blocks_table_and_override(monkeypatch):
     # Small stacks clamp to the padded shape — no 8x over-padding.
     assert tuned_blocks(24, 16, np.float32) == (32, 128)
     assert tuned_blocks(100, 1000, np.float32)[0] <= 128
-    monkeypatch.setenv("FED_REDUCE_BLOCKS", "64,256")
-    assert tuned_blocks(4096, 65536, np.float32) == (64, 256)
+    monkeypatch.setenv("FED_REDUCE_BLOCKS", "128,256")
+    assert tuned_blocks(4096, 65536, np.float32) == (128, 256)
     monkeypatch.setenv("FED_REDUCE_BLOCKS", "garbage")
     with pytest.raises(ValueError):
         tuned_blocks(4096, 65536, np.float32)
+    # An override the TPU tiling refuses is rejected here, not handed to
+    # the chip compiler.
+    for bad in ("64,256", "128,200", "0,128"):
+        monkeypatch.setenv("FED_REDUCE_BLOCKS", bad)
+        with pytest.raises(ValueError, match="tiling"):
+            tuned_blocks(4096, 65536, np.float32)
 
 
 def test_tuned_blocks_drive_pallas_kernel(monkeypatch):
     """The tuned (and overridden) blockings agree with the ref reduction
     through the interpreted kernel path."""
     k = jax.random.PRNGKey(3)
-    stack = jax.random.normal(k, (37, 300))
-    w = jax.random.uniform(jax.random.PRNGKey(4), (37,))
+    stack = jax.random.normal(k, (150, 300))
+    w = jax.random.uniform(jax.random.PRNGKey(4), (150,))
     ref = fed_reduce(stack, w, impl="ref")
     got = fed_reduce(stack, w, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
-    monkeypatch.setenv("FED_REDUCE_BLOCKS", "32,128")
+    monkeypatch.setenv("FED_REDUCE_BLOCKS", "128,128")  # 2x3 grid
     got2 = fed_reduce(stack, w, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got2), np.asarray(ref), atol=1e-5)
 
