@@ -16,17 +16,23 @@
     update rows (int8: dequantize, then reduce), DeviceFlow must conserve
     its rows, and client losses must be finite.  One more f32 round runs its
     cohorts in two worker processes on the host CPUs (``FleetWorkerPool``)
-    and must conserve its rows and bytes.
+    and must conserve its rows and bytes.  The logical tier's local
+    training is checked on its own: the first 512 logical devices' trained
+    params must match a float64 NumPy run of the same local SGD.
 (b) Serving: ``launch/serve.py``'s continuous mode on granite-moe-3b-a800m
     at published widths (bf16 weights from the seed), 16 slots, prompt 128,
     32 decode tokens, 32 requests -- once with the Pallas decode-attention
     kernel and once with the jnp reference.  Every request must finish, and
     one Pallas decode-attention call at the served arena's shape must match
-    ``decode_attention_ref`` within bf16 tolerance.  The share of decoded
-    tokens the two runs agree on is printed.
+    ``decode_attention_ref`` within bf16 tolerance, and so must one Pallas
+    flash-attention call at a 4 x 128-token granite prefill against
+    ``attention_ref`` (no served path takes that kernel).  The share of
+    decoded tokens the two serving runs agree on is printed.
 (c) ``--chips 4``: one round of the same campaign on a 4-way fleet mesh
     (``make_fleet_mesh(4)``: sharded cohorts and ``fed_reduce(mesh=)``, the
-    path of ``train.py --fleet-shards``) against the same round on one chip.
+    path of ``train.py --fleet-shards``) against the same round on one chip,
+    both on the allocator's own split, whose last cohort chunk of each tier
+    does not divide over the four shards.
 
 Compile seconds, each phase's wall time and every check print as they
 happen; the last line is one JSON object naming the device.  The script
@@ -56,7 +62,7 @@ from repro.core import (  # noqa: E402
 )
 from repro.core.devicemodel import GRADES, DeviceFleet  # noqa: E402
 from repro.core.simulation import (  # noqa: E402
-    DeviceTier, GradePlanEntry, HybridSimulation, LogicalTier,
+    DeviceTier, HybridSimulation, LogicalTier,
 )
 from repro.data.synthetic_ctr import make_federated_ctr  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -83,6 +89,7 @@ SERVE_ARGS = ["--arch", "granite_moe_3b_a800m", "--mode", "continuous",
 # (8-bit mantissa) reduction.
 F32_RTOL = 1e-4
 BF16_TOL = 2e-2  # tests/test_kernels.py's bf16 tolerance
+LOCAL_ROWS = 512  # devices checked against the float64 local-train reference
 
 
 class CompileClock:
@@ -137,24 +144,16 @@ def campaign_data(fleet: dict, records: int, dim: int, seed: int):
     return batches, counts
 
 
-def campaign_plan(fleet: dict, *, shards: int = 1) -> RoundPlan:
-    """The allocator's split on fleet-calibrated runtimes.  ``shards > 1``
-    rounds each grade's logical count down to a multiple of it, so every
-    cohort chunk divides over a fleet mesh of that many shards."""
+def campaign_plan(fleet: dict) -> RoundPlan:
+    """The allocator's split on fleet-calibrated runtimes."""
     specs = [GradeSpec(g, n, **RESOURCES[g]) for g, n in fleet.items()]
     cal = RuntimeCalibrator()
     for g in fleet:
         probe = DeviceFleet(GRADES[g], 64, seed=7)
         for r in range(3):
             cal.observe_fleet(probe.run_round(r))
-    plan = RoundPlan.from_allocation(
+    return RoundPlan.from_allocation(
         solve_allocation(specs, cal.runtimes_for(specs)), specs)
-    if shards > 1:
-        plan = RoundPlan(tuple(
-            GradePlanEntry(e.grade, e.num_logical - e.num_logical % shards,
-                           e.num_physical + e.num_logical % shards)
-            for e in plan.entries))
-    return plan
 
 
 def host_fedavg(params, batches) -> list[np.ndarray]:
@@ -176,6 +175,36 @@ def host_fedavg(params, batches) -> list[np.ndarray]:
             for g, s in zip(jax.tree.leaves(params), sums)]
 
 
+def host_local_train(params, batch, *, lr: float, epochs: int) -> list:
+    """float64 NumPy of ``ctr.make_local_train_fn`` on a stack of devices:
+    ``epochs`` full-batch steps of the masked mean BCE.  Returns the trained
+    ``[b, w]`` rows in ``jax.tree.leaves`` order."""
+    x = np.asarray(batch["x"], np.float64)  # (n, records, dim)
+    y = np.asarray(batch["y"], np.float64)
+    mask = np.asarray(batch["mask"], np.float64)
+    n = x.shape[0]
+    w = np.tile(np.asarray(params["w"], np.float64), (n, 1))
+    b = np.full(n, float(params["b"]))
+    denom = np.maximum(mask.sum(1), 1.0)[:, None]
+    for _ in range(epochs):
+        logits = np.einsum("nrd,nd->nr", x, w) + b[:, None]
+        g = (1.0 / (1.0 + np.exp(-logits)) - y) * mask / denom
+        w -= lr * np.einsum("nrd,nr->nd", x, g)
+        b -= lr * g.sum(1)
+    return [b[:, None], w]
+
+
+def update_rows(batches, lo: int, hi: int) -> list[np.ndarray]:
+    """Update rows of global devices ``[lo, hi)``, which one batch holds."""
+    for b in batches:
+        ids = np.asarray(b.device_ids)
+        if ids[0] <= lo and hi - 1 <= ids[-1]:
+            r0 = int(np.asarray(b.rows)[lo - ids[0]])
+            return [np.asarray(leaf[r0:r0 + hi - lo], np.float64)
+                    for leaf in b.buffer.leaves2d]
+    raise LookupError(f"no batch holds devices [{lo}, {hi})")
+
+
 def rel_err(got, ref) -> float:
     """max |got - ref| over max |ref|, across parameter leaves."""
     got = [np.asarray(g, np.float64) for g in jax.tree.leaves(got)]
@@ -188,9 +217,11 @@ def rel_err(got, ref) -> float:
 def run_campaign(name: str, sim: HybridSimulation, svc: AggregationService,
                  flow: DeviceFlow, plan: RoundPlan, batches, counts, *,
                  rounds: int, seed: int, check: Checks,
-                 clock: CompileClock):
+                 clock: CompileClock, check_local: bool = False):
     """``rounds`` planned rounds, each checked against the host reference;
-    returns the last round's outcome."""
+    returns the last round's outcome.  ``check_local`` also checks the last
+    round's first ``LOCAL_ROWS`` logical devices against
+    ``host_local_train`` (the f32 wire only: int8 rows are quantized)."""
     n_dev = plan.total_devices
     for rnd in range(rounds):
         c0, t0 = clock.compile_s, time.perf_counter()
@@ -217,6 +248,8 @@ def run_campaign(name: str, sim: HybridSimulation, svc: AggregationService,
         check(f"{name} round {rnd} losses finite",
               bool(np.isfinite(losses).all()) and losses.size == n_dev,
               f"{losses.size} losses")
+        if check_local and rnd == rounds - 1:
+            check_local_train(name, prev, plan, out, batches, check)
     shelf = flow.shelf(0)
     check(f"{name} DeviceFlow conserves rows", flow.conservation_ok(0)
           and shelf.total_received == rounds * n_dev,
@@ -224,6 +257,31 @@ def run_campaign(name: str, sim: HybridSimulation, svc: AggregationService,
           f"dispatched={shelf.total_dispatched} "
           f"dropped={shelf.total_dropped}")
     return out
+
+
+def check_local_train(name: str, params, plan: RoundPlan, out, batches,
+                      check: Checks) -> None:
+    """The first grade's first ``LOCAL_ROWS`` devices run on the logical
+    tier; their trained params must match float64 NumPy at f32 tolerance.
+    The device tier's first rows are measured against the same reference
+    and printed: its bf16 backend is meant to miss it."""
+    first = plan.entries[0]
+    tiers = (("logical", 0, first.num_logical),
+             ("device", first.num_logical, first.num_devices))
+    for tier, lo, hi in tiers:
+        hi = min(lo + LOCAL_ROWS, hi)
+        batch = {k: np.asarray(v[lo:hi]) for k, v in
+                 batches[first.grade].items()}
+        ref = host_local_train(params, batch, lr=CTR.lr,
+                               epochs=CTR.local_epochs)
+        err = rel_err(update_rows(out.batches, lo, hi), ref)
+        if tier == "logical":
+            check(f"{name} logical local training matches float64 NumPy",
+                  hi - lo == LOCAL_ROWS and err <= F32_RTOL,
+                  f"{hi - lo} devices, rel_err={err:.3e} <= {F32_RTOL:g}")
+        else:
+            print(f"{name}: bf16 device tier against the same reference, "
+                  f"{hi - lo} devices: rel_err={err:.3e}", flush=True)
 
 
 def federated_service(params, counts, *, mesh=None):
@@ -262,7 +320,8 @@ def phase_federated(seed: int, check: Checks, clock: CompileClock) -> None:
         sim = HybridSimulation(logical, tiers=tiers, deviceflow=flow,
                                wire=wire, error_feedback=(wire == "int8"))
         run_campaign(f"a/{wire}", sim, svc, flow, plan, batches, counts,
-                     rounds=ROUNDS, seed=seed, check=check, clock=clock)
+                     rounds=ROUNDS, seed=seed, check=check, clock=clock,
+                     check_local=(wire == "f32"))
         print(f"a/{wire}: {ROUNDS} rounds in "
               f"{time.perf_counter() - t0:.3f} s, "
               f"{flow.shelf(0).total_bytes_dispatched} wire bytes",
@@ -299,6 +358,8 @@ def phase_serving(seed: int, check: Checks, clock: CompileClock) -> None:
     from repro.configs.registry import get_config
     from repro.kernels.decode_attention.ops import (
         decode_attention, decode_attention_ref, tuned_block_k)
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.flash_attention.ref import attention_ref
     from repro.launch import serve
 
     tokens = {}
@@ -338,12 +399,26 @@ def phase_serving(seed: int, check: Checks, clock: CompileClock) -> None:
     lens = jax.random.randint(k3, (slots,), 0, max_len + 1).at[0].set(0)
     block_k = tuned_block_k(max_len, head_dim=cfg.head_dim)
     got = decode_attention(q, kc, vc, lens, impl="pallas", block_k=block_k)
-    ref = decode_attention_ref(q.astype(jnp.float32),
-                               kc.swapaxes(1, 2).astype(jnp.float32),
-                               vc.swapaxes(1, 2).astype(jnp.float32), lens)
+    ref = decode_attention_ref(q.astype(jnp.float32), kc.astype(jnp.float32),
+                               vc.astype(jnp.float32), lens)
     got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
     err = float(np.abs(got - ref).max())
     check("b pallas decode attention matches decode_attention_ref",
+          bool(np.allclose(got, ref, atol=BF16_TOL, rtol=BF16_TOL)),
+          f"max abs err {err:.3e}, bf16 tol {BF16_TOL:g}")
+
+    # One flash-attention call at a 4 x 128-token granite prefill.
+    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    q = jax.random.normal(k0, (4, 128, cfg.num_heads, cfg.head_dim), dt)
+    kv_shape = (4, 128, cfg.num_kv_heads, cfg.head_dim)
+    k = jax.random.normal(k1, kv_shape, dt)
+    v = jax.random.normal(k2, kv_shape, dt)
+    got = flash_attention(q, k, v, causal=True, impl="pallas")
+    ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
+                        v.astype(jnp.float32), causal=True)
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    err = float(np.abs(got - ref).max())
+    check("b pallas flash attention matches attention_ref",
           bool(np.allclose(got, ref, atol=BF16_TOL, rtol=BF16_TOL)),
           f"max abs err {err:.3e}, bf16 tol {BF16_TOL:g}")
 
@@ -353,7 +428,7 @@ def phase_sharded(seed: int, check: Checks, clock: CompileClock,
     from repro.distribution.sharding import make_fleet_mesh
 
     batches, counts = campaign_data(FLEET, RECORDS, CTR.dim, seed)
-    plan = campaign_plan(FLEET, shards=shards)
+    plan = campaign_plan(FLEET)
     mesh = make_fleet_mesh(shards)
     params = {}
     for name, m in (("one chip", None), (f"{shards}-way mesh", mesh)):

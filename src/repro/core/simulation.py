@@ -99,17 +99,37 @@ def _stack_params(params: Params, n: int) -> Params:
 
 
 def _shard_over_data(fn, mesh, data_axis: str, n_in: int, n_out: int):
-    """Wrap a vmapped fn so every arg/output shards over the mesh data axis."""
+    """Wrap a vmapped fn so every arg/output shards over the mesh data axis.
+
+    The allocator's splits do not divide over the axis in general (a grade's
+    last cohort chunk is whatever is left), so a chunk whose rows do not
+    divide is padded with zero rows and the padded rows' outputs are
+    dropped: every row's result is the one it gets unsharded.  (A constant
+    pad partitions cleanly; copies of the last row make the partitioner
+    replicate them.)
+    """
     from jax.sharding import PartitionSpec as P
 
     spec = P(data_axis)
-    return jax.shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec,) * n_in,
         out_specs=(spec,) * n_out if n_out > 1 else spec,
         check_vma=False,
     )
+    shards = int(mesh.shape[data_axis])
+
+    def padded(*args):
+        n = jax.tree.leaves(args)[0].shape[0]
+        pad = (-n) % shards
+        if not pad:
+            return sharded(*args)
+        grow = lambda x: jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+        out = sharded(*jax.tree.map(grow, args))
+        return jax.tree.map(lambda x: x[:n], out)
+
+    return padded
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -269,7 +289,8 @@ class _ZeroCopyCohortMixin:
         return spec
 
 class LogicalTier(_ZeroCopyCohortMixin):
-    """Vectorized logical-simulation tier."""
+    """Vectorized logical-simulation tier: the f32 side of the operator
+    discrepancy, its matmuls at full precision on every backend."""
 
     def __init__(
         self,
@@ -301,7 +322,11 @@ class LogicalTier(_ZeroCopyCohortMixin):
             cast = lambda x: (x.astype(self.dtype)
                               if jnp.issubdtype(x.dtype, jnp.floating) else x)
             stacked = jax.tree.map(cast, _stack_params(global_params, n))
-            return vmapped(stacked, batches, rngs)
+            # Matmuls at the tier's own dtype: a TPU's default f32 matmul is
+            # one bf16 pass, which would erase the operator discrepancy
+            # between this tier and the bf16 device tier.
+            with jax.default_matmul_precision("highest"):
+                return vmapped(stacked, batches, rngs)
 
         self._cohort_fn = cohort
 

@@ -1,10 +1,9 @@
 """Public entry point for decode attention (single-token, KV cache).
 
-``decode_attention`` takes **head-major** caches ``(b, kv, s, d)`` — the
-layout of the continuous-batching KV arena (``core.serving``), chosen so the
-Pallas kernel's K/V tiles are contiguous ``(block_k, d)`` slabs.  The jnp
-reference ``decode_attention_ref`` keeps the sequence-major ``(b, s, kv, d)``
-layout of the fixed-batch model caches; the ``"ref"`` impl transposes.
+``decode_attention`` and its jnp reference ``decode_attention_ref`` take
+**head-major** caches ``(b, kv, s, d)`` — the layout of the
+continuous-batching KV arena (``core.serving``), chosen so the Pallas
+kernel's K/V tiles are contiguous ``(block_k, d)`` slabs.
 
 Besides the attention op itself this module carries the KV-*arena* slot
 paths used by continuous batching: a fixed-capacity cache of shape
@@ -59,9 +58,7 @@ def decode_attention(
             interpret=kernels.pallas_interpret(impl),
         )
     if impl == "ref":
-        return decode_attention_ref(
-            q, k_cache.swapaxes(1, 2), v_cache.swapaxes(1, 2), lengths,
-            scale=scale)
+        return decode_attention_ref(q, k_cache, v_cache, lengths, scale=scale)
     raise ValueError(f"unknown impl {impl!r}")
 
 

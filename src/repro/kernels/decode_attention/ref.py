@@ -1,4 +1,8 @@
-"""Pure-jnp oracle for single-token decode attention over a KV cache."""
+"""Pure-jnp oracle for single-token decode attention over a KV cache.
+
+Caches are head-major ``(b, kv, s, d)``, the layout of the Pallas kernel and
+of the continuous-batching KV arena; the fixed-batch model caches are
+sequence-major and swap their axes at the call."""
 from __future__ import annotations
 
 import jax
@@ -9,14 +13,14 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 def decode_attention_ref(
     q: jax.Array,  # (b, h, d) — one new token per sequence
-    k_cache: jax.Array,  # (b, s, kv, d)
-    v_cache: jax.Array,  # (b, s, kv, d)
+    k_cache: jax.Array,  # (b, kv, s, d) — head-major
+    v_cache: jax.Array,  # (b, kv, s, d)
     lengths: jax.Array,  # (b,) int32 — valid cache entries per sequence
     *,
     scale: float | None = None,
 ) -> jax.Array:
     b, h, d = q.shape
-    kv = k_cache.shape[2]
+    kv = k_cache.shape[1]
     g = h // kv
     scale = (d ** -0.5) if scale is None else scale
     return _decode_scoped(q, k_cache, v_cache, lengths, scale, b, kv, g, d)
@@ -34,12 +38,12 @@ def _decode_impl(q, k_cache, v_cache, lengths, scale, b, kv, g, d):
     # Keep the cache in its storage dtype; accumulate in f32 on the MXU —
     # casting the cache to f32 would triple decode HBM traffic (§Perf).
     qg = (q.reshape(b, kv, g, d) * scale).astype(q.dtype)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32)
-    mask = jnp.arange(k_cache.shape[1])[None] < lengths[:, None]  # (b, s)
+    mask = jnp.arange(k_cache.shape[2])[None] < lengths[:, None]  # (b, s)
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(q.dtype), v_cache,
+    o = jnp.einsum("bkgs,bksd->bkgd", p.astype(q.dtype), v_cache,
                    preferred_element_type=jnp.float32)
     o = o.reshape(b, kv * g, d).astype(q.dtype)
     # length-0 rows (a retired / never-filled KV-arena slot): the all-masked
@@ -50,7 +54,7 @@ def _decode_impl(q, k_cache, v_cache, lengths, scale, b, kv, g, d):
 
 def decode_attention_partial(
     q: jax.Array,  # (b, h, d)
-    k_cache: jax.Array,  # (b, s_shard, kv, d) — one *shard* of the cache
+    k_cache: jax.Array,  # (b, kv, s_shard, d) — one *shard* of the cache
     v_cache: jax.Array,
     lengths: jax.Array,  # (b,) valid entries in THIS shard
     *,
@@ -65,17 +69,17 @@ def decode_attention_partial(
     softmax output of this shard.
     """
     b, h, d = q.shape
-    kv = k_cache.shape[2]
+    kv = k_cache.shape[1]
     g = h // kv
     scale = (d ** -0.5) if scale is None else scale
     qg = q.reshape(b, kv, g, d).astype(jnp.float32) * scale
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache.astype(jnp.float32))
-    mask = jnp.arange(k_cache.shape[1])[None] < lengths[:, None]
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache.astype(jnp.float32))
+    mask = jnp.arange(k_cache.shape[2])[None] < lengths[:, None]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     m = s.max(axis=-1)  # (b, kv, g)
     p = jnp.exp(s - m[..., None])
     l = p.sum(axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bkgs,bksd->bkgd", p, v_cache.astype(jnp.float32))
     return (
         o.reshape(b, h, d),
         m.reshape(b, h),

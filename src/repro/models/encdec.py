@@ -226,7 +226,8 @@ def decode_step(params: Params, token: jax.Array, cfg: ModelConfig,
         from repro.kernels.decode_attention.ops import decode_attention_ref
         s_src = cache["xk"].shape[1]
         lengths = jnp.full((b,), s_src, jnp.int32)
-        oc = decode_attention_ref(qc[:, 0], cache["xk"], cache["xv"], lengths)
+        oc = decode_attention_ref(qc[:, 0], cache["xk"].swapaxes(1, 2),
+                                  cache["xv"].swapaxes(1, 2), lengths)
         h = h + oc.reshape(b, 1, -1) @ lp["cross"]["wo"]
         h = h + mlp_apply(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
         new_cache = dict(sa, xk=cache["xk"], xv=cache["xv"])

@@ -174,7 +174,8 @@ def attention_decode(
     # Plain masked softmax over the (sequence-sharded) cache: GSPMD lowers the
     # softmax reductions over the sharded axis into the flash-decoding
     # max/sum combine (psum over sp); the Pallas kernel is the on-chip analogue.
-    o = decode_attention_ref(q[:, 0], k_cache, v_cache, lengths)
+    o = decode_attention_ref(q[:, 0], k_cache.swapaxes(1, 2),
+                             v_cache.swapaxes(1, 2), lengths)
     out = o.reshape(b, 1, H * hd) @ p["wo"]
     return out, {"k": k_cache, "v": v_cache, "pos": pos + 1}
 

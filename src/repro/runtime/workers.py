@@ -61,7 +61,6 @@ selects this path; see ``examples/quickstart.py`` §11.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
 import time
@@ -323,6 +322,11 @@ class _WorkerState:
 
 def _worker_main(worker_id: int, conn, spec: WorkerSpec,
                  delay_s: float) -> None:
+    import jax
+
+    # Before anything touches a device: the fleet runs on the host CPUs,
+    # whatever backend the inherited environment names.
+    jax.config.update("jax_platforms", "cpu")
     state = _WorkerState(worker_id, spec, delay_s)
     try:
         conn.send(("ready", worker_id))
@@ -366,23 +370,6 @@ class _Seg:
     owner: int
 
 
-@contextlib.contextmanager
-def _environ(overrides: Mapping[str, str]):
-    """Set ``overrides`` in ``os.environ`` for the block, then restore.  A
-    spawned child copies the environment when it is started, so this is how
-    a worker gets variables JAX reads while it is imported."""
-    saved = {k: os.environ.get(k) for k in overrides}
-    os.environ.update(overrides)
-    try:
-        yield
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 class FleetWorkerPool:
     """Coordinator handle on N spawned cohort workers.
 
@@ -391,12 +378,12 @@ class FleetWorkerPool:
     crashed coordinator never strands them.  See the module docstring for
     the transport/recycling/fault model.
 
-    Workers run the fleet on the host CPUs (the host-CPU fleet design): the
-    pool starts every child with ``JAX_PLATFORMS=cpu``, whatever the
-    coordinator's environment says.  An accelerator belongs to one process
-    at a time, and on a TPU host the coordinator holds it (aggregation and
-    serving run there), so a child that tried to initialise the TPU would
-    fail or hang.  Pooled chunks therefore run on the CPU backend; they are
+    Workers run the fleet on the host CPUs (the host-CPU fleet design): each
+    child sets JAX's platform to ``cpu`` before it touches a device,
+    whatever the coordinator's environment says.  An accelerator belongs to
+    one process at a time, and on a TPU host the coordinator holds it
+    (aggregation and serving run there), so a child that tried to
+    initialise the TPU would fail or hang.  Pooled chunks therefore run on the CPU backend; they are
     bit-identical to inline rounds when the coordinator's backend is the CPU
     too.
     """
@@ -441,8 +428,7 @@ class FleetWorkerPool:
             proc = ctx.Process(target=_worker_main,
                                args=(wid, child, self.spec, delay),
                                daemon=True, name=f"fleet-worker-{wid}")
-            with _environ({"JAX_PLATFORMS": "cpu"}):
-                proc.start()
+            proc.start()
             child.close()
             self._workers.append(_WorkerHandle(wid, proc, parent))
         deadline = time.monotonic() + self.start_timeout_s

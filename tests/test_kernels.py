@@ -76,15 +76,13 @@ DECODE_CASES = [
 def test_decode_attention_matches_oracle(case, dtype):
     b, s, h, kv, d = case
     q = jnp.asarray(RNG.standard_normal((b, h, d)), dtype)
-    kc = jnp.asarray(RNG.standard_normal((b, s, kv, d)), dtype)
-    vc = jnp.asarray(RNG.standard_normal((b, s, kv, d)), dtype)
+    kc = jnp.asarray(RNG.standard_normal((b, kv, s, d)), dtype)
+    vc = jnp.asarray(RNG.standard_normal((b, kv, s, d)), dtype)
     lens = jnp.asarray(RNG.integers(1, s + 1, size=b), jnp.int32)
     ref = decode_attention_ref(
         q.astype(jnp.float32), kc.astype(jnp.float32),
         vc.astype(jnp.float32), lens)
-    # The kernel reads head-major (b, kv, s, d) caches.
-    out = decode_attention(q, kc.swapaxes(1, 2), vc.swapaxes(1, 2), lens,
-                           impl="pallas_interpret")
+    out = decode_attention(q, kc, vc, lens, impl="pallas_interpret")
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=tol(dtype), rtol=tol(dtype))
@@ -94,8 +92,8 @@ def test_decode_partial_combine_equals_full():
     """Sequence-sharded flash-decoding: shard partials + combine == full."""
     b, s, h, kv, d, nsh = 2, 512, 8, 2, 64, 8
     q = jnp.asarray(RNG.standard_normal((b, h, d)), jnp.float32)
-    kc = jnp.asarray(RNG.standard_normal((b, s, kv, d)), jnp.float32)
-    vc = jnp.asarray(RNG.standard_normal((b, s, kv, d)), jnp.float32)
+    kc = jnp.asarray(RNG.standard_normal((b, kv, s, d)), jnp.float32)
+    vc = jnp.asarray(RNG.standard_normal((b, kv, s, d)), jnp.float32)
     lens = jnp.asarray(RNG.integers(1, s + 1, size=b), jnp.int32)
     ref = decode_attention_ref(q, kc, vc, lens)
     ssh = s // nsh
@@ -103,7 +101,8 @@ def test_decode_partial_combine_equals_full():
     for i in range(nsh):
         shard_len = jnp.clip(lens - i * ssh, 0, ssh)
         o, m, l = decode_attention_partial(
-            q, kc[:, i * ssh:(i + 1) * ssh], vc[:, i * ssh:(i + 1) * ssh],
+            q, kc[:, :, i * ssh:(i + 1) * ssh],
+            vc[:, :, i * ssh:(i + 1) * ssh],
             shard_len)
         os_.append(o), ms_.append(m), ls_.append(l)
     out = combine_partials(jnp.stack(os_), jnp.stack(ms_), jnp.stack(ls_))

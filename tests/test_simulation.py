@@ -1,4 +1,9 @@
 """Batched round engine: fleet fidelity, cohort numerics, arrival times."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -348,6 +353,70 @@ def test_device_tier_mesh_cohort_matches_unsharded():
     for a, b in zip(jax.tree.leaves(p0), jax.tree.leaves(p1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-6, rtol=1e-6)
+
+
+def test_fleet_mesh_round_pads_uneven_chunks():
+    """A round on a 4-way fleet mesh whose cohort chunks do not divide over
+    the shards (13 logical + 10 device rows, cohorts of 6) matches the same
+    round unsharded, and every update leaf spans the mesh.  Runs in a
+    subprocess because XLA_FLAGS must be set before jax initializes."""
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.core.deviceflow import DeviceFlow
+        from repro.core.devicemodel import GRADES
+        from repro.core.federation import (AggregationService,
+                                           SampleThresholdTrigger)
+        from repro.core.simulation import (DeviceTier, GradePlanEntry,
+                                           HybridSimulation, LogicalTier,
+                                           RoundPlan)
+        from repro.core.strategies import AccumulatedStrategy
+        from repro.distribution.sharding import make_fleet_mesh
+        from test_simulation import _ctr_setup
+
+        assert len(jax.devices()) == 4, jax.devices()
+        local, params, batches, counts = _ctr_setup(n_clients=23)
+        plan = RoundPlan((GradePlanEntry("High", 13, 10),))
+
+        def round_on(mesh):
+            svc = AggregationService(
+                params, trigger=SampleThresholdTrigger(int(counts.sum())),
+                mesh=mesh)
+            flow = DeviceFlow(svc)
+            flow.register_task(0, AccumulatedStrategy(thresholds=(1,)))
+            kw = dict(cohort_size=6, mesh=mesh, data_axis="dp")
+            sim = HybridSimulation(
+                LogicalTier(local, **kw),
+                tiers={"High": DeviceTier(local, GRADES["High"], **kw)},
+                deviceflow=flow)
+            out = sim.run_plan_round(0, 0, params, plan, {"High": batches},
+                                     {"High": counts}, jax.random.PRNGKey(3))
+            flow.run()
+            assert len(svc.history) == 1
+            return out, svc.global_params
+
+        _, ref = round_on(None)
+        out, got = round_on(make_fleet_mesh(4))
+        assert sorted(b.n for b in out.batches) == [1, 4, 6, 6, 6], [
+            b.n for b in out.batches]
+        spans = {len(leaf.sharding.device_set) for b in out.batches
+                 for leaf in b.buffer.leaves2d}
+        assert spans == {4}, spans
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
+        print("MESH_OK")
+    """)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    env["JAX_PLATFORMS"] = "cpu"
+    here = os.path.dirname(os.path.abspath(__file__))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(here, "..", "src"), here, env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert "MESH_OK" in proc.stdout
 
 
 # --------------------------------------------------------------------------- #

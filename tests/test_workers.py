@@ -153,7 +153,7 @@ def test_pool_round_bit_identical(wire, workers, delay):
 
 
 def test_pool_workers_never_take_the_accelerator(monkeypatch):
-    """Workers start with JAX_PLATFORMS=cpu whatever the coordinator's
+    """Workers run on the CPU backend whatever the coordinator's
     environment says: on a TPU host the coordinator holds the chip, and a
     child that tried to initialise it would fail.  Here the coordinator's
     environment names a TPU a CPU-only host does not have, so a child that
@@ -166,7 +166,7 @@ def test_pool_workers_never_take_the_accelerator(monkeypatch):
     got = _run_world("f32", 2)
     _assert_equivalent(ref, got)
     assert got["failures"] == []
-    assert os.environ["JAX_PLATFORMS"] == "tpu"  # coordinator env restored
+    assert os.environ["JAX_PLATFORMS"] == "tpu"  # coordinator env untouched
 
 
 def test_pool_segment_ring_recycles():
