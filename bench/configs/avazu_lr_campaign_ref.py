@@ -64,7 +64,7 @@ def _grade_data(key, *, devices: int, records: int, dim: int,
 
 
 def make_data(seed: int, fleet: dict, records: int, dim: int,
-              recipe: dict, device=None):
+              recipe: dict):
     """Per-grade client batches on the chip and per-device sample counts.
     Each grade draws its own segment preferences and true logit vector."""
     key = seed_key(seed)
@@ -73,8 +73,6 @@ def make_data(seed: int, fleet: dict, records: int, dim: int,
         data = _grade_data(jax.random.fold_in(key, i), devices=n,
                            records=records, dim=dim,
                            recipe=tuple(sorted(recipe.items())))
-        if device is not None:
-            data = jax.device_put(data, device)
         batches[grade] = data
         counts[grade] = np.full(n, records, np.int64)
     return batches, counts

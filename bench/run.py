@@ -39,6 +39,7 @@ import contextlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
+import os  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -117,12 +118,41 @@ class Spans:
                 if n == name and t0 >= w0 and t1 <= w1]
 
 
+class CompileLog:
+    """Host-clock times of JAX's backend compiles and compile-cache reads,
+    so that a run can say what compiled in set-up and in the window."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+    _installed = None
+
+    def __init__(self):
+        self.events: list[tuple[str, float]] = []
+
+    def __call__(self, event: str, duration_s: float, **_) -> None:
+        if event in (self.COMPILE, self.CACHE_READ):
+            self.events.append((event, time.perf_counter()))
+
+    @classmethod
+    def install(cls) -> "CompileLog":
+        """The process's one log (JAX keeps its listeners for good)."""
+        if cls._installed is None:
+            import jax
+
+            cls._installed = cls()
+            jax.monitoring.register_event_duration_secs_listener(
+                cls._installed)
+        return cls._installed
+
+    def count(self, event: str, lo: float, hi: float) -> int:
+        return sum(1 for e, t in self.events if e == event and lo <= t <= hi)
+
+
 class Run:
     """What a per-layer metric reader may read."""
 
     def __init__(self, cell: Cell, spans: Spans, counters: dict, trace,
                  peaks: dict):
-        self.cell = cell
         self.config = cell.config
         self.traffic = cell.traffic
         self.chips = cell.chips
@@ -153,6 +183,8 @@ def execute(cell: Cell, *, seed: int, seconds: float, trace: bool,
     reference.  Returns the result object."""
     import jax
 
+    compiles = CompileLog.install()
+    t_setup = time.perf_counter()
     system = load_module(cell.system_path)
     reference = load_module(cell.reference_path)
     spans = Spans()
@@ -204,7 +236,13 @@ def execute(cell: Cell, *, seed: int, seconds: float, trace: bool,
                                f"end-to-end metric {m['name']}")
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
-    for line in window.get("notes", []):
+    (_, w0, w1), = [r for r in spans.records if r[0] == "window"]
+    notes = [f"{where}: {compiles.count(compiles.COMPILE, lo, hi)} programs "
+             f"compiled, {compiles.count(compiles.CACHE_READ, lo, hi)} read "
+             "from the compile cache"
+             for where, lo, hi in (("set-up", t_setup, w0),
+                                   ("window", w0, w1))]
+    for line in notes + window.get("notes", []):
         print(f"bench: {line}", file=sys.stderr)
     sut.release()
     compared = sut.check()
@@ -228,18 +266,26 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
-def main(argv=None) -> int:
-    args = parse_args(argv)
+def start_jax():
+    """Import JAX with its persistent compilation cache at the fixed
+    ``<checkout>/.jax_cache`` (whatever the environment said), caching every
+    program, also the many that compile in under a second."""
+    cache = str(ROOT / ".jax_cache")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
     sys.path.insert(0, str(ROOT / "src"))
-    sys.path.insert(0, str(BENCH))
-    cell = Cell(load_json(ROOT / "BENCHMARK.json"), args.workload)
-
     import jax
     from repro.launch.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
-    # Cache every program, also the many that compile in under a second.
+    jax.config.update("jax_compilation_cache_dir", enable_compile_cache())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    sys.path.insert(0, str(BENCH))
+    cell = Cell(load_json(ROOT / "BENCHMARK.json"), args.workload)
+    jax = start_jax()
     devices = jax.devices()
     if devices[0].platform != "tpu":
         print(f"bench: JAX finds no TPU (platform {devices[0].platform!r})",

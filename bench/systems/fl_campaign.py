@@ -68,7 +68,7 @@ class System:
         with self.spans("setup.data"):
             self.batches, self.counts = self.ref.make_data(
                 self.seed, cfg["fleet"], cfg["records_per_device"], m["dim"],
-                cfg["data_recipe"], device=self.devices[0])
+                cfg["data_recipe"])
             jax.block_until_ready(self.batches)
         self.plan_ = self.plan()
         mesh = None

@@ -1,88 +1,34 @@
 """A whole run at a small size, the chip look skipped, with the timed path
 broken underneath: ``correct`` has to come out false for every fault the
-cell can have."""
-import jax
-import jax.numpy as jnp
+cell can have (``bench/faults.py``)."""
 import pytest
 
 from conftest import small_run
-from repro.core import federation, serving
-from repro.models import ctr
-
-FL = "ctr_campaign.f32"
+from faults import planted
 
 
-def _jit_like_apply(fn):
-    return jax.jit(fn, static_argnames=("impl", "mesh"))
-
-
-def test_fl_round_that_returns_its_state_unchanged(bench, monkeypatch):
-    monkeypatch.setattr(federation, "_FUSED_REDUCE_APPLY", _jit_like_apply(
-        lambda g, *_, impl, mesh: g))
-    res = small_run(bench, FL)
+@pytest.mark.parametrize("cell_name", ["ctr_campaign.f32",
+                                       "ctr_campaign.mesh4"])
+@pytest.mark.parametrize("fault", ["fl.state_unchanged", "fl.half_batch",
+                                   "fl.answer_altered"])
+def test_fl_faults(bench, cell_name, fault):
+    with planted(fault):
+        res = small_run(bench, cell_name)
     assert not res["correct"], res["compared"]
 
 
-def test_fl_half_of_the_batch_left_out(bench, monkeypatch):
-    """Each chunk's second half of rows weighs nothing; the mean is taken
-    over the rest."""
-
-    def half(g, leaves, scales, wvecs, inv_total, lr, *, impl, mesh):
-        kept = tuple(w * (jnp.arange(w.shape[0]) < w.shape[0] // 2)
-                     for w in wvecs)
-        total = sum(w.sum() for w in kept)
-        return federation._fused_reduce_apply(
-            g, leaves, scales, kept, 1.0 / total, lr, impl=impl, mesh=mesh)
-
-    monkeypatch.setattr(federation, "_FUSED_REDUCE_APPLY",
-                        _jit_like_apply(half))
-    res = small_run(bench, FL)
+def test_fl_exchange_between_chips_left_out(bench):
+    with planted("fl.exchange_left_out"):
+        res = small_run(bench, "ctr_campaign.mesh4")
     assert not res["correct"], res["compared"]
-
-
-def test_fl_answer_altered_where_it_is_produced(bench, monkeypatch):
-    make = ctr.make_local_train_fn
-
-    def altered(**kw):
-        local = make(**kw)
-
-        def train(params, batch, rng):
-            p, m = local(params, batch, rng)
-            return dict(p, w=p["w"].at[0].add(1e-3)), m
-        return train
-
-    monkeypatch.setattr(ctr, "make_local_train_fn", altered)
-    res = small_run(bench, FL)
-    assert not res["correct"], res["compared"]
-
-
-def _patch_decode(monkeypatch, change):
-    real = serving.arena_decode
-
-    def broken(params, tok, active, arena, cfg, **kw):
-        return change(real, params, tok, active, arena, cfg, **kw)
-
-    monkeypatch.setattr(serving, "arena_decode", broken)
 
 
 @pytest.mark.parametrize("cell_name", ["granite_serve.decode",
                                        "granite_serve.open"])
-@pytest.mark.parametrize("fault", ["token_altered", "state_unchanged",
-                                   "half_batch"])
-def test_serving_faults(bench, monkeypatch, cell_name, fault):
-    def token_altered(real, params, tok, active, arena, cfg, **kw):
-        nxt, arena = real(params, tok, active, arena, cfg, **kw)
-        return jnp.where(active, (nxt + 1) % cfg.vocab_size, nxt), arena
-
-    def state_unchanged(real, params, tok, active, arena, cfg, **kw):
-        nxt, _ = real(params, tok, active, arena, cfg, **kw)
-        return nxt, arena
-
-    def half_batch(real, params, tok, active, arena, cfg, **kw):
-        keep = jnp.arange(active.shape[0]) < active.shape[0] // 2
-        nxt, new = real(params, tok, active & keep, arena, cfg, **kw)
-        return nxt, dict(new, lengths=arena["lengths"] + active)
-
-    _patch_decode(monkeypatch, locals()[fault])
-    res = small_run(bench, cell_name)
+@pytest.mark.parametrize("fault", ["serve.token_altered",
+                                   "serve.state_unchanged",
+                                   "serve.half_batch"])
+def test_serving_faults(bench, cell_name, fault):
+    with planted(fault):
+        res = small_run(bench, cell_name)
     assert not res["correct"], res["compared"]

@@ -59,6 +59,7 @@ def test_every_name_resolves_to_its_files(bench):
 
 
 @pytest.mark.parametrize("cell_name", ["ctr_campaign.f32",
+                                       "ctr_campaign.mesh4",
                                        "granite_serve.decode",
                                        "granite_serve.open"])
 def test_a_small_run_is_correct_and_reports_its_metrics(bench, cell_name):

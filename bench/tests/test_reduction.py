@@ -144,3 +144,21 @@ def test_program_readers_tell_decode_from_prefill():
     pre = run.load_module(run.BENCH / "metrics" / "prefill_step_ms.py")
     assert dec.read(Run) == pytest.approx((20 + 20 + 30) / 3 * 1e-6)
     assert pre.read(Run) == pytest.approx((100 + 150) / 2 * 1e-6)
+
+
+def test_collective_reader_counts_each_chip_once_per_round():
+    """Two chips, two rounds: 4 ns and 2 ns of collectives on one chip,
+    6 ns on the other, 12 ns in all; the fusion does not count."""
+
+    class Run:
+        chips = 2
+        counters = {"rounds": 2}
+        trace = tr.Trace(
+            {0: [("all-reduce.1", 0, 4), ("fusion.3", 4, 50),
+                 ("all-reduce-start", 60, 2)],
+             1: [("all-gather.2", 0, 6)]}, [], (0, 100))
+
+    col = run.load_module(run.BENCH / "metrics" / "fl.collective_ms.py")
+    assert col.read(Run) == pytest.approx(12 / 2 / 2 * 1e-6)
+    Run.trace = tr.Trace({0: [("fusion.3", 0, 5)]}, [], (0, 100))
+    assert col.read(Run) is None
