@@ -3,12 +3,14 @@ could take for the traced decode steps' attention (K and V read up to each
 active slot's length, q read and o written; bytes bound it), in every
 layer, over the device time of the kernel's events in the trace.
 
-The kernel's events carry the name of the function passed to
-``pallas_call``."""
+The kernel's events carry the name of its HLO custom call, which XLA takes
+from the innermost jit around the ``pallas_call``: ``decode_attention.<n>``
+(``jit(decode_attention)`` in ``kernels/decode_attention/ops.py``), the only
+instruction of that name in the decode step compiled for a v5e."""
 from flops import decode_attention_cost
 from trace_reduce import op_seconds
 
-KERNEL = r"decode_kernel"
+KERNEL = r"^%?decode_attention(\.\d+)?$"
 
 
 def read(run):

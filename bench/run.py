@@ -14,7 +14,10 @@ harness finds everything else by those names:
   the system's generator reads.
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric, which
   takes its number from the harness's spans, the system's counters and the
-  profiler trace (``--trace 1``).
+  profiler trace (``--trace 1``).  Where that file is missing, the reader
+  of the name's part before its first dot serves (``device_idle.fl`` ->
+  ``device_idle.py``).  A reader that finds nothing returns ``None``, and
+  the run then fails: the cell lists the metric, so it must be there.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a traced window of the
@@ -90,6 +93,19 @@ class Cell:
                           if name in m.get("workloads", [name])
                           and m["moves"] in reported]
         self.metric_dir = bdir / "metrics"
+
+    def reader(self, metric: str) -> pathlib.Path:
+        """``metrics/<metric>.py``, else the reader of the name's first
+        part: ``device_idle.fl`` and ``device_idle.serve_decode`` share
+        ``metrics/device_idle.py``."""
+        own = self.metric_dir / f"{metric}.py"
+        if own.is_file():
+            return own
+        return self.metric_dir / f"{metric.split('.')[0]}.py"
+
+
+class MetricMissing(RuntimeError):
+    """A per-layer metric of the cell found nothing to read."""
 
 
 class Spans:
@@ -176,8 +192,26 @@ def memory_peak_bytes(devices) -> int:
     return int(max(peaks))
 
 
+def per_layer(cell: Cell, run: Run) -> dict:
+    """Every per-layer metric of the cell, read from ``run``.  The cell
+    lists them, so each reader has to find what it reads: a kernel or a span
+    that went out of sight fails the run instead of leaving a gap."""
+    metrics, missing = {}, []
+    for m in cell.per_layer:
+        value = load_module(cell.reader(m["name"])).read(run)
+        if value is None:
+            missing.append(m["name"])
+        else:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if missing:
+        raise MetricMissing(f"per-layer metrics {missing} of {cell.name} "
+                            "found nothing to read in this run")
+    return metrics
+
+
 def execute(cell: Cell, *, seed: int, seconds: float, trace: bool,
-            devices, peaks: dict, overrides: dict | None = None) -> dict:
+            devices, peaks: dict, overrides: dict | None = None,
+            keep_trace: str | None = None) -> dict:
     """Build the cell's system from the seed, warm it up, measure one
     window, read the metrics, then check the window's output against the
     reference.  Returns the result object."""
@@ -194,7 +228,8 @@ def execute(cell: Cell, *, seed: int, seconds: float, trace: bool,
     sut.setup()
     window_s = (min(seconds, cell.traffic["trace_seconds"]) if trace
                 else seconds)
-    trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
+    trace_dir = (keep_trace or tempfile.mkdtemp(prefix="bench_trace_")
+                 if trace else None)
     if trace:
         jax.profiler.start_trace(trace_dir)
     setup_s = time.perf_counter() - T_START
@@ -213,17 +248,12 @@ def execute(cell: Cell, *, seed: int, seconds: float, trace: bool,
         from trace_reduce import busy_s, idle_gaps, load as load_trace, top_ops
 
         tr = load_trace(trace_dir)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        if not keep_trace:
+            shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"] = busy_s(tr)
         device["window_s"] = tr.window_s
-        run = Run(cell, spans, window["counters"], tr, peaks)
-        for m in cell.per_layer:
-            value = load_module(cell.metric_dir / f"{m['name']}.py").read(run)
-            if value is None:
-                print(f"bench: per-layer metric {m['name']} found nothing "
-                      "to read in this run", file=sys.stderr)
-                continue
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        metrics = per_layer(cell, Run(cell, spans, window["counters"], tr,
+                                      peaks))
         first = min(tr.device_ops)
         breakdown = {"device_ops": top_ops(tr.ops()),
                      "idle_gaps": idle_gaps(tr.ops(first), tr.window,
@@ -263,6 +293,8 @@ def parse_args(argv=None):
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--keep-trace", metavar="DIR",
+                    help="write the profiler trace to DIR and keep it")
     return ap.parse_args(argv)
 
 
@@ -300,9 +332,13 @@ def main(argv=None) -> int:
     except KeyError as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
-    result = execute(cell, seed=args.seed, seconds=args.seconds,
-                     trace=bool(args.trace), devices=devices[:cell.chips],
-                     peaks=peaks)
+    try:
+        result = execute(cell, seed=args.seed, seconds=args.seconds,
+                         trace=bool(args.trace), devices=devices[:cell.chips],
+                         peaks=peaks, keep_trace=args.keep_trace)
+    except MetricMissing as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 1
     for name, c in result["compared"].items():
         print(f"compared {name}: {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

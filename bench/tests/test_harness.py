@@ -53,7 +53,7 @@ def test_every_name_resolves_to_its_files(bench):
         assert "setup_s" in ends and len(ends) >= 2
         assert cell.per_layer, w["name"]
         for m in cell.per_layer:
-            assert (cell.metric_dir / f"{m['name']}.py").is_file()
+            assert cell.reader(m["name"]).is_file()
     for c in bench["configs"]:
         assert (ROOT / c["file"]).is_file()
 
@@ -108,3 +108,18 @@ def test_arrivals_keep_the_rate_and_permute_one_draw(process):
         other = np.diff(np.concatenate([[10.0], b]))
         k = min(len(gaps), len(other)) - 5
         assert len(set(np.round(gaps, 9)) & set(np.round(other, 9))) > k // 2
+
+
+def test_a_metric_that_finds_nothing_fails_the_run(bench):
+    """A decode cell's trace without the decode kernel: its readers find
+    nothing, and the run fails instead of printing a line without them."""
+    import trace_reduce as tr
+
+    cell = run.Cell(bench, "granite_serve.decode")
+    trace = tr.Trace({0: [("fusion.1", 10, 5)]}, [], (0, 100),
+                     {0: [("jit_step", 0, 50)]})
+    counters = {"steps": [(0.0, 1.0, 32, 0, [65] * 32)]}
+    r = run.Run(cell, run.Spans(), counters, trace, {
+        "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9})
+    with pytest.raises(run.MetricMissing, match="decode_step_ms"):
+        run.per_layer(cell, r)

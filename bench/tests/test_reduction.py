@@ -133,8 +133,8 @@ def test_program_readers_tell_decode_from_prefill():
         counters = {"steps": [(0, 0, 0, 1, []), (0, 0, 0, 1, []),
                               (0, 0, 0, 0, [])]}
         trace = tr.Trace(
-            {0: [("_decode_kernel", 115, 1), ("_decode_kernel", 365, 1),
-                 ("_decode_kernel", 405, 1)]}, [], (0, 1000),
+            {0: [("decode_attention.6", 115, 1), ("decode_attention.6", 365, 1),
+                 ("decode_attention.6", 405, 1)]}, [], (0, 1000),
             {0: [("jit__lambda", 0, 100), ("jit__lambda", 100, 5),
                  ("jit__lambda", 110, 20), ("jit__lambda", 200, 150),
                  ("jit__lambda", 350, 5), ("jit__lambda", 360, 20),
