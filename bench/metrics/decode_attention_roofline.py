@@ -4,13 +4,14 @@ active slot's length, q read and o written; bytes bound it), in every
 layer, over the device time of the kernel's events in the trace.
 
 The kernel's events carry the name of its HLO custom call, which XLA takes
-from the innermost jit around the ``pallas_call``: ``decode_attention.<n>``
+from the innermost jit around the ``pallas_call`` (seen in a v5e trace:
+``%decode_attention.6``, ``%fed_reduce_pallas.3``): ``decode_attention.<n>``
 (``jit(decode_attention)`` in ``kernels/decode_attention/ops.py``), the only
 instruction of that name in the decode step compiled for a v5e."""
 from flops import decode_attention_cost
 from trace_reduce import op_seconds
 
-KERNEL = r"^%?decode_attention(\.\d+)?$"
+KERNEL = r"^%?decode_attention(\.\d+)?( =|$)"
 
 
 def read(run):

@@ -3,7 +3,7 @@ the compiled-program executions in the trace that run the decode-attention
 kernel (no other serving program does)."""
 from trace_reduce import containing, matching
 
-KERNEL = r"^%?decode_attention(\.\d+)?$"  # see decode_attention_roofline
+KERNEL = r"^%?decode_attention(\.\d+)?( =|$)"  # see decode_attention_roofline
 
 
 def read(run):

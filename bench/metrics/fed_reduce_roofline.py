@@ -4,13 +4,14 @@ bytes over HBM bandwidth and their operations over the bf16 peak; the bytes
 bound it) over the device time of the kernel's events in the trace.
 
 The kernel's events carry the name of its HLO custom call, which XLA takes
-from the innermost jit around the ``pallas_call``: ``fed_reduce_pallas.<n>``
+from the innermost jit around the ``pallas_call`` (seen in a v5e trace:
+``%decode_attention.6``, ``%fed_reduce_pallas.3``): ``fed_reduce_pallas.<n>``
 (the jitted ``fed_reduce_pallas``), the only instructions of that name in
 the streaming partial reduce compiled for a v5e."""
 from flops import fed_reduce_cost
 from trace_reduce import op_seconds
 
-KERNEL = r"^%?fed_reduce_pallas(\.\d+)?$"
+KERNEL = r"^%?fed_reduce_pallas(\.\d+)?( =|$)"
 
 
 def read(run):

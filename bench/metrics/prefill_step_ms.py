@@ -5,7 +5,7 @@ the harness counted admitting steps (the others are the small token
 scatter that follows each prefill)."""
 from trace_reduce import containing, matching
 
-KERNEL = r"^%?decode_attention(\.\d+)?$"  # see decode_attention_roofline
+KERNEL = r"^%?decode_attention(\.\d+)?( =|$)"  # see decode_attention_roofline
 
 
 def read(run):

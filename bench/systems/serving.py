@@ -10,7 +10,9 @@ Traffic, one generator for every mix:
 
 * ``kind: serve_closed``: a standing backlog.  Before each engine step the
   queue is topped up to ``backlog`` requests, so slots refill as they
-  retire.  Reports ``serve_tokens_per_s``.
+  retire.  The harness waits on the step ``ahead_steps`` behind the newest,
+  so the chip stays fed while the host stalls.  Reports
+  ``serve_tokens_per_s``.
 * ``kind: serve_open``: an open loop.  Arrival times come from the seed
   (``arrivals.process``: ``poisson`` at ``rate_per_s``, or ``on_off``:
   bursts of ``on_s`` seconds at ``rate_per_s * (on_s + off_s) / on_s``
@@ -20,17 +22,21 @@ Traffic, one generator for every mix:
   Each request is timed from its scheduled time.  Reports
   ``serve_ttft_p90_ms`` and ``serve_itl_p99_ms``.
 
-In both, the harness blocks on each step's tokens, so every token has the
-host time at which its step ended.  Rounds of the loop start while the
-window is open; the window closes when the last started step has ended.
+In the open loop the harness blocks on each step's tokens, so every token
+has the host time at which its step ended.  Steps start while the window is
+open; the window closes when every step started in it has ended.
 
-Check: a sample drawn from the seed of the requests the window finished
-(``check_requests`` of them); the reference runs over each prompt with its
-served tokens, and ``max_logit_gap`` is the widest gap by which a served
-token's reference logit lies below the reference's best at its position.
+Check: the slots are split into ``check_requests`` equal groups (at most
+one a slot), and from each, requests that the window finished are drawn
+from the seed, ``check_requests`` in all, so the sample spans the batch.  The reference runs over each prompt with its
+served tokens; at each position, the gap is how far the served token's
+reference logit lies below the reference's best.  ``max_logit_gap`` is the
+widest gap, ``mean_logit_gap`` the mean over every position; the check
+compares those that the configuration's ``limits`` name.
 """
 from __future__ import annotations
 
+import collections
 import gc
 import time
 
@@ -107,6 +113,10 @@ class System:
         self.token_t: dict[int, list[float]] = {}  # request id -> token times
         self.steps: list[tuple] = []  # (t0, t1, tokens, decode lengths)
         self.live: dict = {}  # request id -> [record, decode tokens seen]
+        # Steps dispatched and not yet waited on (their ``lengths``).
+        self.pending: collections.deque = collections.deque()
+        self.ahead = (tr.get("ahead_steps", 0) if tr["kind"] == "serve_closed"
+                      else 0)
         with self.spans("setup.warmup"):
             # One request through prefill and decode compiles every shape
             # the traffic uses; it stays in its slot and serves on.
@@ -114,6 +124,7 @@ class System:
             self.step()
             self.step()
             self.start_arrivals()
+            self.drain()
 
     def now(self) -> float:
         return time.perf_counter() - self.clock0
@@ -135,8 +146,10 @@ class System:
                    for r in eng.slot_owner if r is not None]
         with self.spans("serve.step"):
             eng.step(t0)
+        self.pending.append(eng.arena["lengths"])
         with self.spans("serve.wait"):
-            jax.block_until_ready(eng.arena["lengths"])
+            while len(self.pending) > self.ahead:
+                jax.block_until_ready(self.pending.popleft())
         t1 = self.now()
         for rec in queued:
             if rec.slot is not None and rec.request_id not in self.live:
@@ -153,6 +166,11 @@ class System:
                 del self.live[rid]
         admitted = sum(1 for r in queued if r.slot is not None)
         self.steps.append((t0, t1, tokens + admitted, admitted, lengths))
+
+    def drain(self) -> None:
+        with self.spans("serve.wait"):
+            while self.pending:
+                jax.block_until_ready(self.pending.popleft())
 
     def serve_until(self, t_stop: float) -> None:
         """Run the loop until ``t_stop``: top up the backlog (closed) or
@@ -193,8 +211,9 @@ class System:
         first_step = len(self.steps)
         self.lateness = []
         self.serve_until(w1)
+        self.drain()  # every step sent in the window has ended
         steps = self.steps[first_step:]
-        t_end = max(steps[-1][1] if steps else w1, w1)
+        t_end = max(self.now(), w1)
         span = t_end - w0
         notes, metrics = [], {}
         if self.traffic["kind"] == "serve_closed":
@@ -224,15 +243,22 @@ class System:
 
     # -- check --------------------------------------------------------------
     def release(self) -> None:
-        """Fetch every token once, keep the sampled requests, free the
-        engine and its KV arena."""
+        """Fetch every token once, keep the sampled requests (finished
+        requests from each group of slots), free the engine and its KV
+        arena."""
         report = self.engine.report()
         by_id = {r.request_id: r for r in report.records}
-        k = min(self.traffic["check_requests"], len(self.finished))
-        pick = self.rng_sample.choice(len(self.finished), size=k, replace=False)
-        self.sample = [(np.asarray(by_id[self.finished[i]].prompt),
-                        np.asarray(by_id[self.finished[i]].tokens))
-                       for i in sorted(pick)]
+        k, slots = self.traffic["check_requests"], self.traffic["slots"]
+        n = min(k, slots)
+        groups: dict[int, list[int]] = {}
+        for rid in self.finished:
+            groups.setdefault(by_id[rid].slot * n // slots, []).append(rid)
+        pick = []
+        for i, (_, g) in enumerate(sorted(groups.items())):
+            for _ in range(min(k // n + (i < k % n), len(g))):
+                pick.append(g.pop(self.rng_sample.integers(len(g))))
+        self.sample = [(np.asarray(by_id[r].prompt),
+                        np.asarray(by_id[r].tokens)) for r in pick]
         del self.engine, report, by_id
         gc.collect()
 
@@ -255,18 +281,24 @@ class System:
             out.append(ref.max(-1) - ref[np.arange(len(pos)), tok])
         return out
 
+    @staticmethod
+    def summary(gaps: list[np.ndarray]) -> dict:
+        if not gaps:
+            return {"max_logit_gap": float("nan"),
+                    "mean_logit_gap": float("nan")}
+        g = np.concatenate(gaps)
+        return {"max_logit_gap": float(g.max()),
+                "mean_logit_gap": float(g.mean())}
+
     def check(self) -> dict:
         limits = self.cfg["limits"]
         if not self.sample:
             return {"finished_sampled": {"value": float("inf"),
-                                         "limit": limits["max_logit_gap"]}}
-        gap = max(float(g.max()) for g in self.gaps())
-        return {"max_logit_gap": {"value": gap,
-                                  "limit": limits["max_logit_gap"]}}
+                                         "limit": min(limits.values())}}
+        got = self.summary(self.gaps())
+        return {k: {"value": got[k], "limit": v} for k, v in limits.items()}
 
     def control(self) -> dict:
-        """``max_logit_gap`` of the tokens that the fp8 reference puts
-        first, at the same positions of the same sampled requests."""
-        gaps = self.gaps(mode="fp8")
-        return {"max_logit_gap": (max(float(g.max()) for g in gaps)
-                                  if gaps else float("nan"))}
+        """The gaps of the tokens that the fp8 reference puts first, at the
+        same positions of the same sampled requests."""
+        return self.summary(self.gaps(mode="fp8"))

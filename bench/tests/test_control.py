@@ -20,4 +20,4 @@ def test_control_is_not_correct(bench, cell_name):
     small = SMALL[cell.config["system"]].get("config", {})
     limits = small.get("limits", cell.config["limits"])
     assert all(out["program"][k] <= limits[k] for k in out["program"])
-    assert any(v > limits[k] for k, v in out["control"].items()), out
+    assert any(out["control"][k] > limits[k] for k in limits), out

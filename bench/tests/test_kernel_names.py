@@ -1,11 +1,13 @@
 """The names the kernel readers match are the names the chip's trace shows.
 
-A TPU trace's ``XLA Ops`` events carry the HLO instruction names of the
-optimized program, and a Pallas kernel's instruction is named after the
-innermost jit around its ``pallas_call``.  So the program's own jits are
-compiled here for a described v5e (no chip needed) and every
-``tpu_custom_call`` instruction of each must match its reader's pattern:
-a rename in the program fails this test before it fails a run on the chip.
+A TPU trace's ``XLA Ops`` events carry the whole HLO instruction of the
+optimized program (``%decode_attention.6 = bf16[...] custom-call(...)``),
+and a Pallas kernel's instruction is named after the innermost jit around
+its ``pallas_call``.  So the program's own jits are compiled here for a
+described v5e (no chip needed) and every ``tpu_custom_call`` instruction of
+each, as the trace shows it and as ``trace_reduce`` keeps it, must match its
+reader's pattern: a rename in the program fails this test before it leaves
+a gap in a run on the chip.
 """
 import dataclasses
 import re
@@ -16,9 +18,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import run
+import trace_reduce
 
-CUSTOM_CALL = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=.*custom_call_target="
-                         r"\"tpu_custom_call\"", re.M)
+CUSTOM_CALL = re.compile(r"^\s*(?:ROOT\s+)?(%\S+ = .*custom_call_target="
+                         r"\"tpu_custom_call\".*)$", re.M)
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +54,10 @@ def chip(topo, monkeypatch):
 
 
 def kernel_names(fn, *args) -> list[str]:
-    return CUSTOM_CALL.findall(jax.jit(fn).lower(*args).compile().as_text())
+    """Each kernel instruction as a trace event names it, and as the
+    reduction keeps that name."""
+    lines = CUSTOM_CALL.findall(jax.jit(fn).lower(*args).compile().as_text())
+    return [n for line in lines for n in (line, trace_reduce.short_name(line))]
 
 
 def reader_pattern(metric: str) -> str:
@@ -66,7 +72,7 @@ def test_fed_reduce_reader_sees_the_streaming_partial_reduce(chip):
                                                    impl="pallas"),
         chip((1024, 1), jnp.float32), chip((1024, 256), jnp.float32),
         chip((1024,), jnp.float32))
-    assert len(names) == 2  # one weighted row-sum per leaf
+    assert len(names) == 2 * 2  # one weighted row-sum per leaf
     pattern = reader_pattern("fed_reduce_roofline")
     assert all(re.search(pattern, n) for n in names), names
 

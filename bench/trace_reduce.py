@@ -4,7 +4,9 @@ idle gaps.
 The profiler writes ``<dir>/plugins/profile/<run>/<host>.xplane.pb``.  Each
 TPU is a plane named ``/device:TPU:<i>``; its ``XLA Ops`` line holds one
 event per operation that ran on the chip, with a start and a duration in
-nanoseconds.  The harness's own spans (``jax.profiler.TraceAnnotation``,
+nanoseconds.  A TPU trace names each such event by its whole HLO instruction
+(``%decode_attention.6 = bf16[32,8,3,64]{...} custom-call(...)``); only the
+instruction's name, the part before `` = ``, is kept.  The harness's own spans (``jax.profiler.TraceAnnotation``,
 names starting ``bench.``) are events of the ``/host:CPU`` plane, on the
 same clock.
 
@@ -79,7 +81,7 @@ def load(trace_dir: str, *, window_span: str = "bench.window") -> Trace:
             for line_name, out in ((OPS_LINE, device_ops),
                                    (MODULES_LINE, modules)):
                 out[int(m.group(1))] = [
-                    (e.name, e.start_ns, e.duration_ns)
+                    (short_name(e.name), e.start_ns, e.duration_ns)
                     for line in plane.lines if line.name == line_name
                     for e in line.events]
         elif plane.name == "/host:CPU":
@@ -87,6 +89,11 @@ def load(trace_dir: str, *, window_span: str = "bench.window") -> Trace:
                          for line in plane.lines for e in line.events
                          if e.name.startswith(SPAN_PREFIX))
     return Trace(device_ops, spans, span_window(spans, window_span), modules)
+
+
+def short_name(name: str) -> str:
+    """``%fusion.3 = f32[8]{0} fusion(...)`` -> ``%fusion.3``."""
+    return name.split(" = ", 1)[0]
 
 
 def span_window(spans: list[Event], name: str) -> tuple[float, float]:
