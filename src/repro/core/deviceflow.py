@@ -50,6 +50,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.analysis import sanitizers
 from repro.core.strategies import (
     AccumulatedStrategy,
@@ -547,6 +548,9 @@ class Dispatcher:
         """
         if not isinstance(self.strategy, AccumulatedStrategy):
             return
+        self._traced(self._on_message, t)
+
+    def _on_message(self, t: float) -> None:
         while len(self.shelf) >= (thr := self.strategy.threshold_at(self._cycle)):
             batch = self.shelf.take(thr)
             self._cycle += 1
@@ -557,12 +561,17 @@ class Dispatcher:
         order) landed at times ``ts``; dispatch once per threshold crossing.
 
         Equivalent to calling ``on_message(ts[j])`` after each insertion, but
-        O(dispatch events) instead of O(rows) Python work — the batch plane
-        rides this unchanged because it only reasons about *counts*.
-        Pre-existing backlog above the threshold drains at ``t_base``.
+        O(dispatch events) Python work, not a loop over the rows — the batch
+        plane rides this unchanged because it only reasons about *counts*.
+        A dispatch event is one threshold crossing, so a threshold of 1 makes
+        one event, and one delivery, per row.  Pre-existing backlog above the
+        threshold drains at ``t_base``.
         """
         if not isinstance(self.strategy, AccumulatedStrategy):
             return
+        self._traced(self._on_messages, ts, t_base)
+
+    def _on_messages(self, ts: np.ndarray, t_base: float) -> None:
         k = len(ts)
         pre = len(self.shelf) - k  # rows buffered before this bulk insert
         arrived = consumed = 0
@@ -582,6 +591,30 @@ class Dispatcher:
             consumed += thr
             self._send(t_evt, batch, self.strategy.failure_prob, 0)
 
+    def _traced(self, dispatch: Callable, *args) -> None:
+        """Run one dispatch call.  While tracing is on it runs in a
+        ``flow.dispatch`` span, with ``deliver`` timed, and counts once for
+        the call: ``flow.deliveries``, ``flow.deliver_ns`` (the time spent in
+        ``deliver``, e.g. an aggregation service's intake) and
+        ``flow.rows_dispatched``.  Off, nothing is added per delivery."""
+        if not tracing.on() or isinstance(self.deliver, tracing.Timed):
+            # Off, or inside a traced dispatch of this dispatcher's own
+            # delivery, which already counts and times these deliveries.
+            dispatch(*args)
+            return
+        deliver = self.deliver
+        self.deliver = timed = tracing.Timed(deliver)
+        rows0 = self.shelf.total_dispatched
+        try:
+            with tracing.span("flow.dispatch"):
+                dispatch(*args)
+                tracing.count("flow.deliveries", timed.n)
+                tracing.count("flow.deliver_ns", timed.ns)
+                tracing.count("flow.rows_dispatched",
+                              self.shelf.total_dispatched - rows0)
+        finally:
+            self.deliver = deliver
+
     # -- rule-based path -----------------------------------------------------
     def on_round_complete(self, t: float, clock: "VirtualClock") -> None:
         """Called when a task round completes; schedules rule-based dispatch."""
@@ -598,6 +631,9 @@ class Dispatcher:
             )
 
     def _dispatch_point(self, t: float, p) -> None:
+        self._traced(self._dispatch_point_now, t, p)
+
+    def _dispatch_point_now(self, t: float, p) -> None:
         batch = self.shelf.take(p.count)
         self._send(t, batch, p.failure_prob, p.random_discard)
 
@@ -778,6 +814,11 @@ class DeviceFlow:
         message that crossed it — identical semantics to per-message
         ``submit`` in time order, minus the per-message Python overhead.
         """
+        with tracing.span("flow.submit"):
+            self._submit_many(msgs, ts)
+
+    def _submit_many(self, msgs: Iterable[Message],
+                     ts: "np.ndarray | Sequence[float] | None") -> None:
         msgs = list(msgs)
         if not msgs:
             return
@@ -833,9 +874,16 @@ class DeviceFlow:
         one call, globally merged by arrival time per task.
 
         Dispatch-group membership and threshold-crossing timestamps match
-        per-message submits in time order exactly; only O(items + dispatch
-        events) Python work is done, never O(rows).
+        per-message submits in time order exactly.  The Python work is
+        O(items + dispatch events), with the rows handled in numpy; a dispatch
+        event is one threshold crossing, so under a threshold of 1 that is one
+        event, and one delivery downstream, per row.
         """
+        with tracing.span("flow.submit"):
+            self._submit_arrivals(items, ts)
+
+    def _submit_arrivals(self, items: "Sequence[ArrivalBatch | Message]",
+                         ts: "np.ndarray | Sequence[float] | None") -> None:
         items = [it for it in items if _item_rows(it)]
         if not items:
             return

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.deviceflow import (
     ArrivalBatch,
     Delivery,
@@ -456,6 +457,10 @@ class AggregationService:
             self.aggregate(t)
 
     def aggregate(self, t: float) -> AggregationEvent | None:
+        with tracing.span("agg.apply", round_idx=self.round_idx):
+            return self._aggregate(t)
+
+    def _aggregate(self, t: float) -> AggregationEvent | None:
         n_stream = self._stream_clients
         n_batch = self._pending_batch_rows
         if not self._pending and not n_stream and not n_batch:

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.analysis.sanitizers import hot_path
 from repro.configs.base import ModelConfig
 from repro.distribution import ctx as shard_ctx
@@ -417,11 +418,18 @@ class ContinuousBatchingEngine:
         """Run one iteration starting at virtual time ``t``; returns its
         duration (cost-model virtual seconds).
 
-        ``@hot_path``: the decode loop must never host-sync per iteration —
-        token materialization is deferred to :meth:`_materialize_tokens`
-        (one sync for the whole run), and every h2d transfer here is an
-        explicit ``jnp.asarray``.
+        ``@hot_path`` (here and on ``_step``, which lint rule R003 scans):
+        the decode loop must never host-sync per iteration — token
+        materialization is deferred to :meth:`_materialize_tokens` (one sync
+        for the whole run), and every h2d transfer here is an explicit
+        ``jnp.asarray``.
         """
+        step = len(self.iterations)
+        with tracing.span("serve.step", step=step):
+            return self._step(t, step)
+
+    @hot_path
+    def _step(self, t: float, step: int) -> float:
         admitted: list[RequestRecord] = []
         while self.queue and self._free:
             slot = heapq.heappop(self._free)
@@ -441,10 +449,11 @@ class ContinuousBatchingEngine:
                 for i, rec in enumerate(admitted):
                     toks[i, : len(rec.prompt)] = rec.prompt
                     sids[i] = rec.slot
-                sids_dev = jnp.asarray(sids)
-                first, self.arena = self._prefill(
-                    self.params, jnp.asarray(toks), sids_dev, self.arena)
-                self._tok = self._scatter_tok(self._tok, sids_dev, first)
+                with tracing.span("serve.prefill", step=step):
+                    sids_dev = jnp.asarray(sids)
+                    first, self.arena = self._prefill(
+                        self.params, jnp.asarray(toks), sids_dev, self.arena)
+                    self._tok = self._scatter_tok(self._tok, sids_dev, first)
                 self._events.append(("prefill", list(admitted), first))
         active = [o is not None for o in self.slot_owner]
         n_active = sum(active)
@@ -455,9 +464,10 @@ class ContinuousBatchingEngine:
                 # device_put (an eager dtype conversion would count as an
                 # implicit transfer under the guard).
                 act_host = np.fromiter(active, np.bool_, count=self.slots)
-                nxt, self.arena = self._decode(
-                    self.params, self._tok,
-                    jnp.asarray(act_host), self.arena)
+                with tracing.span("serve.decode", step=step):
+                    nxt, self.arena = self._decode(
+                        self.params, self._tok,
+                        jnp.asarray(act_host), self.arena)
                 self._tok = nxt
                 self._events.append(("decode", list(self.slot_owner), nxt))
             end = t + dur

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.analysis import sanitizers
 from repro.analysis.sanitizers import hot_path
 from repro.core.allocation import AllocationResult
@@ -1016,7 +1017,8 @@ class HybridSimulation:
             def finish(i, buf, metrics):
                 _, _, lo, hi, _ = chunk_plan[i]
                 n_before = len(emissions)
-                emit_handles(buf, lo, hi)
+                with tracing.span("fl.chunk", round_idx=round_idx):
+                    emit_handles(buf, lo, hi)
                 if metrics_out is not None:
                     metrics_out.append(metrics)
                 if stream:
@@ -1034,7 +1036,8 @@ class HybridSimulation:
         else:
             for sim_tier, _, lo, hi, sub in chunk_plan:
                 n_before = len(emissions)
-                run_chunk(sim_tier, lo, hi, sub)
+                with tracing.span("fl.chunk", round_idx=round_idx):
+                    run_chunk(sim_tier, lo, hi, sub)
                 if stream:
                     stream_chunk(n_before)
 
@@ -1042,12 +1045,13 @@ class HybridSimulation:
         # updates become host pytrees, after the whole grade has dispatched.
         # (Columnar mode: bench rows live at ``bench_pos[r]``; scalar mode:
         # emission index == grade-local row.)
-        for r in materialize_rows:
-            i = bench_pos.get(r, r)
-            m = emissions[i]
-            if isinstance(m.payload, UpdateHandle):
-                emissions[i] = dataclasses.replace(
-                    m, payload=m.payload.materialize())
+        with tracing.span("fl.materialize", round_idx=round_idx):
+            for r in materialize_rows:
+                i = bench_pos.get(r, r)
+                m = emissions[i]
+                if isinstance(m.payload, UpdateHandle):
+                    emissions[i] = dataclasses.replace(
+                        m, payload=m.payload.materialize())
         if transform is not None:
             if stream:
                 # Streamed chunks transformed at submit time; only the
@@ -1092,6 +1096,14 @@ class HybridSimulation:
         never changes a grade's total — batches stay shaped ``(N_i, ...)``
         across every re-plan.
         """
+        with tracing.span("fl.round", round_idx=round_idx):
+            return self._plan_round(
+                task_id, round_idx, global_params, plan, grade_batches,
+                grade_num_samples, rng, calibrator=calibrator)
+
+    def _plan_round(self, task_id, round_idx, global_params, plan,
+                    grade_batches, grade_num_samples, rng, *,
+                    calibrator) -> FederatedRoundOutcome:
         # Validate the whole plan up front: a failure mid-plan would leave
         # earlier grades' tiers, rng, and the calibrator polluted with a
         # half-executed round.
@@ -1145,15 +1157,16 @@ class HybridSimulation:
             # allocator-excluded benchmarking devices — also materialize full
             # RoundReports (paper §IV.C) re-stamped with the same global
             # device ids their messages carry.
-            sample = tier.sample_round(np.arange(n_total), round_idx)
-            for k in range(n_total - entry.num_benchmarking, n_total):
-                rep = dataclasses.replace(
-                    sample.report(k), device_id=offset + k)
-                reports.append(rep)
-                tier.reports.append(rep)
-            if calibrator is not None:
-                calibrator.observe_fleet(sample)
-            offsets_s = sample.arrival_offsets_s()
+            with tracing.span("fl.fleet_sample", round_idx=round_idx):
+                sample = tier.sample_round(np.arange(n_total), round_idx)
+                for k in range(n_total - entry.num_benchmarking, n_total):
+                    rep = dataclasses.replace(
+                        sample.report(k), device_id=offset + k)
+                    reports.append(rep)
+                    tier.reports.append(rep)
+                if calibrator is not None:
+                    calibrator.observe_fleet(sample)
+                offsets_s = sample.arrival_offsets_s()
             arrivals.append(base + offsets_s)
             breakdown[entry.grade] = GradeRoundBreakdown(
                 grade=entry.grade,
