@@ -367,17 +367,27 @@ class AggregationService:
     def _on_batch(self, t: float, b: ArrivalBatch) -> None:
         """Columnar intake: one ArrivalBatch slice, all accounting
         vectorized — the 10^6-messages/s path never touches per-row
-        objects."""
+        objects.  A one-row slice (threshold-1 dispatch delivers every
+        device alone) does the same accounting on scalars: numpy's
+        per-call cost would dwarf one row's work."""
         if b.buffer is None:
             raise ValueError(
                 "AggregationService needs buffer-backed ArrivalBatches "
                 "(metadata-only batches carry no model update)")
-        self._pending_samples += b.total_samples
-        stamped = ~np.isnan(b.created_t)
-        if stamped.any():
-            self._pending_latency += float(
-                np.clip(t - b.created_t[stamped], 0.0, None).sum())
-        if self.streaming and self._stream_aligned(b.buffer):
+        if b.n == 1:
+            self._pending_samples += int(b.num_samples[0])
+            created = float(b.created_t[0])
+            if created == created:  # NaN: unstamped
+                self._pending_latency += max(0.0, t - created)
+        else:
+            self._pending_samples += b.total_samples
+            stamped = ~np.isnan(b.created_t)
+            if stamped.any():
+                self._pending_latency += float(
+                    np.clip(t - b.created_t[stamped], 0.0, None).sum())
+        # An open chunk exists only for an aligned buffer.
+        if self.streaming and (id(b.buffer) in self._chunks
+                               or self._stream_aligned(b.buffer)):
             self._stream_add_batch(b)
         else:
             self._pending_batches.append(b)
@@ -391,11 +401,21 @@ class AggregationService:
         return w
 
     def _weights_of(self, b: ArrivalBatch) -> np.ndarray:
-        """Per-row aggregation weights of a batch (vectorized ``_weight``)."""
+        """Per-row aggregation weights of a batch (vectorized ``_weight32``)."""
         w = b.num_samples.astype(np.float32)
         if self.staleness_discount is not None:
             w = w * np.float32(self.staleness_discount(
                 max(0, self.round_idx - b.round_idx)))
+        return w
+
+    def _weight32(self, num_samples, round_idx: int) -> np.float32:
+        """One row's streaming weight, in f32 exactly as ``_weights_of``
+        computes it, so every intake path leaves bit-identical chunk
+        weights for ``fed_reduce``."""
+        w = np.float32(num_samples)
+        if self.staleness_discount is not None:
+            w = w * np.float32(self.staleness_discount(
+                max(0, self.round_idx - round_idx)))
         return w
 
     def _stream_aligned(self, buffer) -> bool:
@@ -407,40 +427,49 @@ class AggregationService:
 
     def _stream_add(self, m: Message) -> None:
         h = m.payload
-        key = id(h.buffer)
-        ch = self._chunks.get(key)
+        self._stream_add_row(h.buffer, h.row,
+                             self._weight32(m.num_samples, m.round_idx))
+
+    def _chunk(self, buffer) -> _StreamChunk:
+        ch = self._chunks.get(id(buffer))
         if ch is None:
-            ch = self._chunks[key] = _StreamChunk(
-                h.buffer,
-                np.zeros(h.buffer.num_rows, np.float32),
-                np.zeros(h.buffer.num_rows, np.float32))
-        ch.weights[h.row] += self._weight(m)
-        if ch.hits[h.row] == 0.0:
+            ch = self._chunks[id(buffer)] = _StreamChunk(
+                buffer,
+                np.zeros(buffer.num_rows, np.float32),
+                np.zeros(buffer.num_rows, np.float32))
+        return ch
+
+    def _stream_add_row(self, buffer, row: int, w: np.float32) -> None:
+        """One row into its chunk, in O(1): the scalar form of
+        ``_stream_add_batch``."""
+        ch = self._chunk(buffer)
+        ch.weights[row] += w
+        if ch.hits[row] == 0.0:
             ch.filled += 1
-        ch.hits[h.row] += 1.0
-        ch.clients += 1
-        self._stream_clients += 1
+        ch.hits[row] += 1.0
+        self._stream_landed(ch, 1)
+
+    def _stream_add_batch(self, b: ArrivalBatch) -> None:
+        """Vectorized ``_stream_add_row``: one scatter per batch slice, its
+        cost proportional to the slice's rows, never to the chunk's."""
+        if b.n == 1:
+            self._stream_add_row(b.buffer, int(b.rows[0]),
+                                 self._weight32(b.num_samples[0], b.round_idx))
+            return
+        ch = self._chunk(b.buffer)
+        # Rows first seen in this slice, a row repeated in it once.
+        ch.filled += np.unique(b.rows[ch.hits[b.rows] == 0.0]).size
+        np.add.at(ch.weights, b.rows, self._weights_of(b))
+        np.add.at(ch.hits, b.rows, np.float32(1.0))
+        self._stream_landed(ch, b.n)
+
+    def _stream_landed(self, ch: _StreamChunk, n: int) -> None:
+        ch.clients += n
+        self._stream_clients += n
         if ch.filled == ch.buffer.num_rows:
             # The chunk has fully landed: fire its fed_reduce partial now —
             # the (async) reduction overlaps the remaining chunks' compute.
-            self._fire_chunk(key)
-
-    def _stream_add_batch(self, b: ArrivalBatch) -> None:
-        """Vectorized ``_stream_add``: one scatter per batch slice."""
-        key = id(b.buffer)
-        ch = self._chunks.get(key)
-        if ch is None:
-            ch = self._chunks[key] = _StreamChunk(
-                b.buffer,
-                np.zeros(b.buffer.num_rows, np.float32),
-                np.zeros(b.buffer.num_rows, np.float32))
-        np.add.at(ch.weights, b.rows, self._weights_of(b))
-        np.add.at(ch.hits, b.rows, np.float32(1.0))
-        ch.filled = int(np.count_nonzero(ch.hits))
-        ch.clients += b.n
-        self._stream_clients += b.n
-        if ch.filled == ch.buffer.num_rows:
-            self._fire_chunk(key)
+            self._fire_chunk(id(ch.buffer))
 
     def _fire_chunk(self, key: int) -> None:
         ch = self._chunks.pop(key)

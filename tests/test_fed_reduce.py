@@ -9,7 +9,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.checkpointer import Checkpointer
-from repro.core.deviceflow import DeviceFlow, Delivery, Message, payload_nbytes
+from repro.core.deviceflow import (
+    ArrivalBatch, DeviceFlow, Delivery, Message, payload_nbytes)
 from repro.core.devicemodel import GRADES
 from repro.core.federation import (
     AggregationService,
@@ -17,6 +18,7 @@ from repro.core.federation import (
     fedavg_delta,
     fused_fedavg_delta,
     handles_align,
+    polynomial_staleness,
 )
 from repro.core.simulation import DeviceTier, HybridSimulation, LogicalTier
 from repro.core.strategies import AccumulatedStrategy
@@ -435,6 +437,182 @@ def test_streaming_state_dict_roundtrip():
     assert len(svc2.history) == 1
     np.testing.assert_allclose(np.asarray(svc2.global_params["w"]),
                                np.asarray(ref.global_params["w"]))
+
+    # The scalar path: one-row slices over two rounds, snapshotted with
+    # chunk 1 partly filled, restore to the uninterrupted run's timeline.
+    bufs = [UpdateBuffer.from_stacked({"w": jnp.asarray([[2.0], [4.0]])}),
+            UpdateBuffer.from_stacked({"w": jnp.asarray([[6.0], [8.0],
+                                                         [10.0]])})]
+    order = [(0, 0), (1, 0), (0, 1), (1, 1), (1, 2)]
+    deliveries = [
+        Delivery(float(4 * r + i), batch=ArrivalBatch(
+            0, r, [row], created_t=[float(i) if i % 2 else np.nan],
+            num_samples=[1 + row], buffer=bufs[k]))
+        for r in range(2) for i, (k, row) in enumerate(order)]
+
+    def timeline(svc):
+        return [(ev.t, ev.round_idx, ev.num_clients, ev.num_samples,
+                 ev.mean_latency_s, np.asarray(ev.global_params["w"]))
+                for ev in svc.history]
+
+    ref = AggregationService({"w": jnp.zeros(1)},
+                             trigger=ClientCountTrigger(5), streaming=True)
+    for d in deliveries:
+        ref(d)
+    svc1 = AggregationService({"w": jnp.zeros(1)},
+                              trigger=ClientCountTrigger(5), streaming=True)
+    for d in deliveries[:4]:
+        svc1(d)
+    assert svc1._chunks[id(bufs[1])].filled == 2  # of 3 rows
+    svc2 = AggregationService({"w": jnp.zeros(1)},
+                              trigger=ClientCountTrigger(5), streaming=True)
+    svc2.load_state_dict(svc1.state_dict())
+    for d in deliveries[4:]:
+        svc2(d)
+    got, want = timeline(svc2), timeline(ref)
+    assert len(got) == len(want) == 2
+    for g, w in zip(got, want):
+        assert g[:5] == w[:5]
+        np.testing.assert_array_equal(g[5], w[5])
+
+
+def _stream_rows(rng, n_rows, n_extra):
+    """One chunk's delivery order: every row once, plus ``n_extra``
+    repeats of rows already seen, with the completing row last.  (A row
+    repeated after the chunk completed opens a new chunk, which a slice
+    holding both rows cannot mirror, so every path is fed repeats only
+    before completion.)"""
+    perm = rng.permutation(n_rows)
+    seq = list(perm[:-1])
+    for _ in range(n_extra if n_rows > 1 else 0):
+        row = perm[int(rng.integers(0, n_rows - 1))]
+        first = seq.index(row)
+        seq.insert(int(rng.integers(first + 1, len(seq) + 1)), row)
+    return np.asarray(seq + [perm[-1]], np.int32)
+
+
+def _intake_state(svc):
+    """Everything the streaming intake accumulates, in exact (byte) form."""
+    return (
+        svc._pending_samples, svc._pending_latency, svc._stream_clients,
+        svc.round_idx,
+        {key: (ch.weights.tobytes(), ch.hits.tobytes(), ch.filled,
+               ch.clients) for key, ch in svc._chunks.items()},
+        [id(ch.buffer) for ch in svc._fired],
+        [(w, [np.asarray(leaf).tobytes() for leaf in leaves])
+         for leaves, w in svc._partials],
+        [(ev.t, ev.round_idx, ev.num_clients, ev.num_samples,
+          ev.mean_latency_s,
+          [np.asarray(v).tobytes() for v in jax.tree.leaves(ev.global_params)])
+         for ev in svc.history],
+    )
+
+
+@settings(max_examples=12, deadline=None)
+@given(chunks=st.lists(st.integers(1, 6), min_size=1, max_size=3),
+       schedule=st.lists(st.integers(1, 7), min_size=1, max_size=3),
+       alpha=st.sampled_from([None, 0.5, 1.5]),
+       stamping=st.sampled_from(["none", "all", "some"]),
+       wire=st.sampled_from(["f32", "int8"]),
+       seed=st.integers(0, 10_000))
+def test_streaming_intake_paths_bit_identical(chunks, schedule, alpha,
+                                              stamping, wire, seed):
+    """Property: the same rows fed as one-row ``Delivery`` slices (the
+    scalar path), as multi-row slices cut by a threshold ``schedule`` (the
+    vectorized path) and as scalar ``Message``s leave bit-identical chunk
+    weights, hits and filled counts, pending samples and latency, fired
+    partials, aggregation events and params at every slice boundary.
+    Creation and delivery times lie on a grid of quarter seconds, so every
+    sum of latencies is exact in f64 and summation order cannot show."""
+    rng = np.random.default_rng(seed)
+    make = (UpdateBuffer.quantized_from_stacked if wire == "int8"
+            else UpdateBuffer.from_stacked)
+    bufs = [make(_stream_tree(rng, n)) for n in chunks]
+    g0 = {"w": jnp.asarray(rng.standard_normal((4, 8)), jnp.float32),
+          "b": jnp.asarray(rng.standard_normal(3), jnp.float32)}
+    n_extra = [int(x) for x in rng.integers(0, 3, len(bufs))]
+    per_round = sum(n + (e if n > 1 else 0) for n, e in zip(chunks, n_extra))
+    plan = []  # per round: (t, slice) in delivery order
+    for r in range(2):
+        cuts = []
+        for buf, extra in zip(bufs, n_extra):
+            rows = _stream_rows(rng, buf.num_rows, extra)
+            n = len(rows)
+            created = rng.integers(0, 400, n) / 4.0
+            if stamping != "all":
+                created[rng.random(n) < (1.0 if stamping == "none" else 0.5)] \
+                    = np.nan
+            batch = ArrivalBatch(0, int(rng.integers(0, r + 7)), rows,
+                                 created_t=created,
+                                 num_samples=rng.integers(1, 41, n),
+                                 buffer=buf)
+            own, lo = [], 0
+            while lo < n:
+                hi = min(n, lo + schedule[len(own) % len(schedule)])
+                own.append(batch.islice(lo, hi))
+                lo = hi
+            cuts.append(own)
+        order = rng.permutation(
+            np.repeat(np.arange(len(bufs)), [len(c) for c in cuts]))
+        nxt = [iter(c) for c in cuts]
+        plan.append([(rng.integers(0, 400) / 4.0, next(nxt[k]))
+                     for k in order])
+
+    def service():
+        svc = AggregationService(
+            jax.tree.map(jnp.array, g0),
+            trigger=ClientCountTrigger(per_round),
+            staleness_discount=(None if alpha is None
+                                else polynomial_staleness(alpha)),
+            streaming=True)
+        svc.round_idx = 5  # batch round_idx in [0, 7]: staleness 0 to 5
+        return svc
+
+    one, multi, msg = service(), service(), service()
+    for r, deliveries in enumerate(plan):
+        for t, b in deliveries:
+            multi(Delivery(t, batch=b))
+            for i in range(b.n):
+                one(Delivery(t, batch=b.islice(i, i + 1)))
+                msg(Delivery(t, message=b.message(i)))
+            want = _intake_state(multi)
+            assert _intake_state(one) == want
+            assert _intake_state(msg) == want
+        assert multi.round_idx == 5 + r + 1
+
+
+@pytest.mark.parametrize("intake,slices", [
+    ("batch", [[0], [0], [1], [2], [3]]),
+    ("message", [[0], [0], [1], [2], [3]]),
+    ("batch", [[0, 0, 1], [1, 2], [3]]),
+    ("batch", [[2, 0, 2], [0], [1, 3]]),
+])
+def test_streaming_counts_rows_once_and_fires_on_completion(intake, slices):
+    """A row delivered twice, inside one slice or across two deliveries,
+    counts once in ``filled`` and twice in ``hits``; the chunk's partial
+    fires at exactly the delivery that completes it."""
+    buf = UpdateBuffer.from_stacked(
+        {"w": jnp.arange(8.0, dtype=jnp.float32).reshape(4, 2)})
+    svc = AggregationService({"w": jnp.zeros(2)},
+                             trigger=ClientCountTrigger(99), streaming=True)
+    batch = ArrivalBatch(0, 0, np.concatenate(slices), buffer=buf)
+    hits = np.zeros(4, np.float32)
+    lo = 0
+    for k, rows in enumerate(slices):
+        part = batch.islice(lo, lo + len(rows))
+        lo += len(rows)
+        if intake == "message":
+            svc(Delivery(0.0, message=part.message(0)))
+        else:
+            svc(Delivery(0.0, batch=part))
+        np.add.at(hits, rows, 1.0)
+        last = k == len(slices) - 1
+        assert len(svc._partials) == int(last)
+        ch = svc._fired[0] if last else svc._chunks[id(buf)]
+        assert ch.filled == np.count_nonzero(hits)
+        np.testing.assert_array_equal(ch.hits, hits)
+        np.testing.assert_array_equal(ch.weights, hits)
+    assert svc._partials[0][1] == float(hits.sum())
 
 
 def test_update_buffer_validation_and_repr():
