@@ -11,7 +11,8 @@ import dataclasses
 import math
 from typing import Literal
 
-Family = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
+Family = Literal["dense", "moe", "ssm", "hybrid", "granite_hybrid", "vlm",
+                 "audio"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +33,11 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    # Expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of the router's num_experts (0 = all).
+    experts_held: int = 0
+    expert_offset: int = 0
+    shared_expert_ff: int = 0  # a shared SwiGLU expert every token takes
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -39,8 +45,13 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_conv_width: int = 4
     ssm_chunk: int = 128
+    ssm_impl: str = "auto"  # ssd_scan impl of the prefill (auto | chunked | ...)
+    ssm_decode_impl: str = "auto"  # ssd_decode impl (auto | pallas | ref | ...)
     # Hybrid (zamba2-style): one shared attention block applied every k layers
     hybrid_attn_every: int = 0
+    # Granite 4.0-H (granite_hybrid): each layer's mixer, "mamba" or
+    # "attention"; every layer also has the (held-expert) MoE feed-forward.
+    layer_types: tuple[str, ...] = ()
     # Encoder-decoder
     num_encoder_layers: int = 0
     # Modality frontend stub (vlm/audio): embeddings are precomputed inputs
@@ -48,6 +59,12 @@ class ModelConfig:
     frontend_tokens: int = 256
     # misc
     rope_theta: float = 10000.0
+    use_rope: bool = True  # False: NoPE attention
+    # Granite's scalars; the defaults apply none of them.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None  # replaces 1/sqrt(head_dim)
+    logits_scaling: float = 1.0  # logits are divided by it
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     scan_layers: bool = True  # homogeneous stacks lower via lax.scan
@@ -56,6 +73,10 @@ class ModelConfig:
     fuse_qkv: bool = False  # beyond-paper perf: merged QKV / gate-up projections
     dtype: str = "bfloat16"
 
+    def __post_init__(self):
+        # JSON gives a list; a config is a static jit argument, so hashable.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+
     @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
@@ -63,7 +84,7 @@ class ModelConfig:
     @property
     def supports_long_context(self) -> bool:
         """Sub-quadratic memory path exists (SSM / hybrid)."""
-        return self.family in ("ssm", "hybrid")
+        return self.family in ("ssm", "hybrid", "granite_hybrid")
 
     @property
     def d_inner(self) -> int:
@@ -72,6 +93,10 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     def num_params(self) -> int:
         """Exact parameter count (used for 6ND model-FLOPs and memory)."""
@@ -93,6 +118,13 @@ class ModelConfig:
         if self.family == "ssm":
             per_layer = self._mamba_block_params() + D
             return embed + self.num_layers * per_layer + D
+        if self.family == "granite_hybrid":
+            moe = (self.held_experts * mlp + D * self.num_experts
+                   + 3 * D * self.shared_expert_ff + norms)
+            n_attn = self.layer_types.count("attention")
+            n_ssm = len(self.layer_types) - n_attn
+            return (embed + n_ssm * self._mamba_block_params()
+                    + n_attn * attn + len(self.layer_types) * moe + D)
         if self.family == "hybrid":
             ssm_layers = self.num_layers * (self._mamba_block_params() + D)
             n_attn_applications = self.num_layers // max(self.hybrid_attn_every, 1)

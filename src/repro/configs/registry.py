@@ -11,6 +11,7 @@ ARCH_IDS = (
     "zamba2_1_2b",
     "mamba2_1_3b",
     "granite_moe_3b_a800m",
+    "granite_4_0_h_small",
     "phi3_5_moe_42b_a6_6b",
     "internvl2_26b",
     "seamless_m4t_medium",
@@ -26,6 +27,7 @@ ALIASES = {
     "zamba2-1.2b": "zamba2_1_2b",
     "mamba2-1.3b": "mamba2_1_3b",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
+    "granite-4.0-h-small": "granite_4_0_h_small",
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b_a6_6b",
     "internvl2-26b": "internvl2_26b",
     "seamless-m4t-medium": "seamless_m4t_medium",

@@ -31,6 +31,14 @@ Stale-KV safety: a reused slot's rows beyond the new prompt keep the retired
 request's K/V, but the slot's length counter is reset at prefill and only
 ever covers rows the current occupant wrote — attention masks the rest
 (tested against a zero-filled cache in ``tests/test_kernels.py``).
+
+Models that keep their own per-layer state (recurrent state beside K/V,
+as the ``granite_hybrid`` family does) bring slot steps through their
+``ModelApi`` (``init_state``, ``prefill_slots``, ``decode_slots``), and the
+three arena functions dispatch to them.  A length counter cannot mask stale
+recurrent state, so such a prefill overwrites all of a slot's state; with
+``ModelApi.donate_state`` the engine donates the state to each step, which
+updates it in place.
 """
 from __future__ import annotations
 
@@ -212,7 +220,12 @@ def init_params(cfg: ModelConfig, seed: int) -> Any:
 def init_arena(cfg: ModelConfig, slots: int, max_len: int) -> dict:
     """Fixed-capacity KV arena: per-layer head-major ``(slots, kv, max_len,
     hd)`` caches plus one per-slot ``lengths`` counter (0 = empty/retired
-    slot)."""
+    slot).  A model with slot steps keeps its own per-layer state under
+    ``layers`` instead of ``kv``."""
+    lengths = jnp.zeros((slots,), jnp.int32)
+    init_state = get_model(cfg).init_state
+    if init_state is not None:
+        return {"layers": init_state(cfg, slots, max_len), "lengths": lengths}
     dt = jnp.dtype(cfg.dtype)
 
     def one():
@@ -224,7 +237,7 @@ def init_arena(cfg: ModelConfig, slots: int, max_len: int) -> dict:
             lambda x: jnp.broadcast_to(x, (cfg.num_layers,) + x.shape), one())
     else:
         kv = [one() for _ in range(cfg.num_layers)]
-    return {"kv": kv, "lengths": jnp.zeros((slots,), jnp.int32)}
+    return {"kv": kv, "lengths": lengths}
 
 
 def _mlp_or_moe(lp, hn, cfg):
@@ -263,6 +276,14 @@ def arena_prefill(params, tokens: jax.Array, slot_ids: jax.Array,
     Returns ``(first greedy token (m,) int32, arena')`` — the prefill's
     last-position logits already yield each request's first token.
     """
+    prefill_slots = get_model(cfg).prefill_slots
+    if prefill_slots is not None:
+        logits, layers = prefill_slots(params, tokens, slot_ids,
+                                       arena["layers"], cfg)
+        tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+        lengths = arena["lengths"].at[slot_ids].set(tokens.shape[1],
+                                                    mode="drop")
+        return tok, {"layers": layers, "lengths": lengths}
     x = embed_apply(params["embed"], tokens)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
@@ -301,6 +322,15 @@ def arena_decode(params, tok: jax.Array, active: jax.Array, arena: dict,
     """
     slots = tok.shape[0]
     lengths = arena["lengths"]
+    decode_slots = get_model(cfg).decode_slots
+    if decode_slots is not None:
+        logits, layers = decode_slots(
+            params, tok, active, lengths, arena["layers"], cfg,
+            attn_impl=attn_impl, block_k=block_k)
+        nxt = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+        return (jnp.where(active, nxt, tok),
+                {"layers": layers,
+                 "lengths": lengths + active.astype(jnp.int32)})
     kv = arena["kv"]
     max_len = (kv["k"].shape[3] if cfg.scan_layers else kv[0]["k"].shape[2])
     if block_k is None:
@@ -329,6 +359,11 @@ def arena_decode(params, tok: jax.Array, active: jax.Array, arena: dict,
     nxt = jnp.argmax(logits[:, : cfg.vocab_size], axis=-1).astype(jnp.int32)
     nxt = jnp.where(active, nxt, tok)
     return nxt, {"kv": kv, "lengths": lengths + active.astype(jnp.int32)}
+
+
+def _state_and_lengths(arena: dict) -> tuple[dict, jax.Array]:
+    return ({k: v for k, v in arena.items() if k != "lengths"},
+            arena["lengths"])
 
 
 # --------------------------------------------------------------------------- #
@@ -366,18 +401,29 @@ class ContinuousBatchingEngine:
             if cfg is None:
                 raise ValueError("cfg required unless simulate_only=True")
             api = get_model(cfg)
-            if api.prefill is None or api.decode_step is None:
+            if api.prefill_slots is None and not api.gqa_arena:
                 raise ValueError(f"family {cfg.family!r} has no serving path")
             self.params = (params if params is not None
                            else init_params(cfg, seed))
             self.arena = init_arena(cfg, slots, self.max_len)
             self._tok = jnp.zeros((slots,), jnp.int32)
-            self._prefill = jax.jit(
-                lambda p, t, sids, ar: arena_prefill(p, t, sids, ar, cfg))
-            self._decode = jax.jit(
-                lambda p, tok, act, ar: arena_decode(
-                    p, tok, act, ar, cfg, attn_impl=attn_impl,
-                    block_k=block_k))
+            # The arena's state goes in apart from ``lengths`` (callers may
+            # still hold that), so a model that asks can have the state
+            # donated and updated in place.
+            donate = (3,) if api.donate_state else ()
+            prefill = jax.jit(
+                lambda p, t, sids, state, n: arena_prefill(
+                    p, t, sids, {**state, "lengths": n}, cfg),
+                donate_argnums=donate, keep_unused=True)
+            decode = jax.jit(
+                lambda p, tok, act, state, n: arena_decode(
+                    p, tok, act, {**state, "lengths": n}, cfg,
+                    attn_impl=attn_impl, block_k=block_k),
+                donate_argnums=donate, keep_unused=True)
+            self._prefill = lambda p, t, sids, ar: prefill(
+                p, t, sids, *_state_and_lengths(ar))
+            self._decode = lambda p, tok, act, ar: decode(
+                p, tok, act, *_state_and_lengths(ar))
             # Jitted so the drop-mode sentinel is a traced constant; the
             # eager .at[].set ships it as a runtime scalar, an implicit
             # h2d that would trip the @hot_path transfer guard.
@@ -449,7 +495,8 @@ class ContinuousBatchingEngine:
                 for i, rec in enumerate(admitted):
                     toks[i, : len(rec.prompt)] = rec.prompt
                     sids[i] = rec.slot
-                with tracing.span("serve.prefill", step=step):
+                with tracing.span("serve.prefill", step=step,
+                                  family=self.cfg.family):
                     sids_dev = jnp.asarray(sids)
                     first, self.arena = self._prefill(
                         self.params, jnp.asarray(toks), sids_dev, self.arena)
@@ -464,7 +511,8 @@ class ContinuousBatchingEngine:
                 # device_put (an eager dtype conversion would count as an
                 # implicit transfer under the guard).
                 act_host = np.fromiter(active, np.bool_, count=self.slots)
-                with tracing.span("serve.decode", step=step):
+                with tracing.span("serve.decode", step=step,
+                                  family=self.cfg.family):
                     nxt, self.arena = self._decode(
                         self.params, self._tok,
                         jnp.asarray(act_host), self.arena)

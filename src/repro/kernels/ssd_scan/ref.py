@@ -10,7 +10,8 @@ Per head ``h`` with state ``S in R^{P x N}`` (P = head dim, N = state dim):
 (SSD) algorithm — quadratic within a chunk, linear across chunks — which is
 what the Pallas kernel implements and what the model code lowers on non-TPU
 backends.  ``ssd_decode_step`` is the O(1) single-token state update used by
-``serve_step``.
+``serve_step``; ``ssd_decode_ref`` is the same update on the serving arena's
+state layout (``ssd_decode.py``) for the active slots only.
 """
 from __future__ import annotations
 
@@ -146,3 +147,32 @@ def ssd_decode_step(
     )
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch)
     return y.astype(x.dtype), state
+
+
+def ssd_decode_ref(
+    x: jax.Array,  # (b, h, p)
+    dt: jax.Array,  # (b, h)
+    A: jax.Array,  # (h,)
+    B: jax.Array,  # (b, g, n)
+    C: jax.Array,  # (b, g, n)
+    state: jax.Array,  # (b, h/f, n, f*p) f32: ssd_decode.to_decode_layout
+    active: jax.Array,  # (b,) bool
+) -> tuple[jax.Array, jax.Array]:
+    """Single-token update of the active slots' folded state; the others'
+    state is returned unchanged and their y is 0.  Returns (y (b, h, p) f32,
+    state)."""
+    b, h, p = x.shape
+    g = B.shape[1]
+    hf, n, fp = state.shape[1:]
+    f = fp // p
+    dt = dt.astype(jnp.float32)
+    u = (dt[..., None] * x.astype(jnp.float32)).reshape(b, hf, fp)
+    a = jnp.repeat(jnp.exp(dt * A[None]), p, axis=1).reshape(b, hf, fp)
+    grp = jnp.arange(hf) * f // (h // g)
+    Bp = B.astype(jnp.float32)[:, grp]  # (b, hf, n)
+    Cp = C.astype(jnp.float32)[:, grp]
+    s = a[:, :, None, :] * state + Bp[..., None] * u[:, :, None, :]
+    y = jnp.sum(s * Cp[..., None], axis=2)
+    y = jnp.where(active[:, None, None], y, 0.0)
+    s = jnp.where(active[:, None, None, None], s, state)
+    return y.reshape(b, h, p), s

@@ -105,14 +105,16 @@ def _project_qkv(p: Params, x: jax.Array, cfg: ModelConfig):
             v.reshape(b, s, KV, hd))
 
 
-def _attend(q, k, v, cfg: ModelConfig, *, causal: bool, q_offset: int = 0):
+def _attend(q, k, v, cfg: ModelConfig, *, causal: bool, q_offset: int = 0,
+            scale: float | None = None):
     impl = cfg.attention_impl
     if impl == "auto":
         impl = "chunked" if q.shape[1] * k.shape[1] > 2048 * 2048 else "einsum"
     if impl == "einsum":
-        return attention_ref(q, k, v, causal=causal, q_offset=q_offset)
+        return attention_ref(q, k, v, causal=causal, q_offset=q_offset,
+                             scale=scale)
     return flash_attention(
-        q, k, v, causal=causal, q_offset=q_offset, impl=impl,
+        q, k, v, causal=causal, q_offset=q_offset, impl=impl, scale=scale,
         block_k=min(cfg.attention_kv_chunk, k.shape[1]),
     )
 

@@ -38,6 +38,12 @@ def _dims(cfg: ModelConfig):
 
 
 def block_init(key, cfg: ModelConfig) -> Params:
+    return {"ln": jnp.ones((cfg.d_model,), jnp.dtype(cfg.dtype)),
+            **mixer_init(key, cfg)}
+
+
+def mixer_init(key, cfg: ModelConfig) -> Params:
+    """The Mamba2 mixer's weights, without the block's pre-norm."""
     dt = jnp.dtype(cfg.dtype)
     D = cfg.d_model
     di, g, n, h, conv_dim = _dims(cfg)
@@ -52,7 +58,6 @@ def block_init(key, cfg: ModelConfig) -> Params:
     # z/x/dt outputs are head-sharded over tp; B/C are per-group (replicated
     # when groups < tp).  Functionally identical to the fused in_proj.
     return {
-        "ln": jnp.ones((D,), dt),
         "in_z": truncated_normal_init(kz, (D, di), dt),
         "in_x": truncated_normal_init(kx, (D, di), dt),
         "in_BC": truncated_normal_init(kbc, (D, 2 * g * n), dt),
@@ -121,10 +126,18 @@ def block_apply(p: Params, x: jax.Array, cfg: ModelConfig,
 def block_prefill(p: Params, x: jax.Array, cfg: ModelConfig,
                   *, impl: str = "auto") -> tuple[jax.Array, dict]:
     """Like block_apply but returns the decode cache (conv tail + ssm state)."""
-    b, l, D = x.shape
+    y, cache = mixer_prefill(p, rmsnorm(x, p["ln"], cfg.norm_eps), cfg,
+                             impl=impl)
+    return x + y, cache
+
+
+def mixer_prefill(p: Params, hn: jax.Array, cfg: ModelConfig,
+                  *, impl: str = "auto") -> tuple[jax.Array, dict]:
+    """The mixer over a normed sequence ``hn`` (b, l, d): its output (no
+    residual) and the decode cache (conv tails, f32 ssm state (b,h,p,n))."""
+    b, l, D = hn.shape
     di, g, n, h, conv_dim = _dims(cfg)
     width = cfg.ssm_conv_width
-    hn = rmsnorm(x, p["ln"], cfg.norm_eps)
     z, xp, BC_raw, dt_raw = _project(p, hn)
     xs = _causal_conv(xp, p["conv_x_w"], p["conv_x_b"])
     BC = _causal_conv(BC_raw, p["conv_BC_w"], p["conv_BC_b"])
@@ -138,43 +151,51 @@ def block_prefill(p: Params, x: jax.Array, cfg: ModelConfig,
         chunk=min(cfg.ssm_chunk, l), impl=impl,
     )
     y = y + p["D_skip"][None, None, :, None] * xs.reshape(b, l, h, cfg.ssm_head_dim).astype(jnp.float32)
-    y = y.reshape(b, l, di).astype(x.dtype)
+    y = y.reshape(b, l, di).astype(hn.dtype)
     y = rmsnorm_gated(y, z, p["norm_w"], cfg.norm_eps)
     cache = {
-        "conv_x": xp[:, l - (width - 1):].astype(x.dtype),
-        "conv_BC": BC_raw[:, l - (width - 1):].astype(x.dtype),
+        "conv_x": xp[:, l - (width - 1):].astype(hn.dtype),
+        "conv_BC": BC_raw[:, l - (width - 1):].astype(hn.dtype),
         "ssm": state,
     }
-    return x + y @ p["out_proj"], cache
+    return y @ p["out_proj"], cache
 
 
 def block_decode(p: Params, x: jax.Array, cfg: ModelConfig,
                  cache: dict) -> tuple[jax.Array, dict]:
     """One-token recurrent update: x (b, 1, d)."""
-    b = x.shape[0]
+    y, cache = mixer_decode(p, rmsnorm(x, p["ln"], cfg.norm_eps), cfg, cache)
+    return x + y, cache
+
+
+def mixer_decode(p: Params, hn: jax.Array, cfg: ModelConfig, cache: dict,
+                 *, state_update=None) -> tuple[jax.Array, dict]:
+    """The mixer's one-token step over a normed ``hn`` (b, 1, d): its output
+    (no residual) and the cache advanced one token.  ``state_update(x, dt,
+    A, B, C, state) -> (y (b,h,p), state)`` advances ``cache["ssm"]``; it
+    defaults to ``ssd_decode_step`` on the (b, h, p, n) state."""
+    b = hn.shape[0]
     di, g, n, h, conv_dim = _dims(cfg)
-    width = cfg.ssm_conv_width
-    hn = rmsnorm(x, p["ln"], cfg.norm_eps)
     z, xp, BC_raw, dt_raw = _project(p, hn)
     conv_x_in = jnp.concatenate([cache["conv_x"], xp], axis=1)  # (b, width, di)
     conv_BC_in = jnp.concatenate([cache["conv_BC"], BC_raw], axis=1)
     cx = (conv_x_in * p["conv_x_w"]).sum(axis=1, keepdims=True) + p["conv_x_b"]
     cbc = (conv_BC_in * p["conv_BC_w"]).sum(axis=1, keepdims=True) + p["conv_BC_b"]
-    xs = jax.nn.silu(cx.astype(jnp.float32)).astype(x.dtype)[:, 0]
-    BC = jax.nn.silu(cbc.astype(jnp.float32)).astype(x.dtype)[:, 0]
+    xs = jax.nn.silu(cx.astype(jnp.float32)).astype(hn.dtype)[:, 0]
+    BC = jax.nn.silu(cbc.astype(jnp.float32)).astype(hn.dtype)[:, 0]
     B, C = jnp.split(BC, 2, axis=-1)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    y, state = ssd_decode_step(
+    y, state = (state_update or ssd_decode_step)(
         xs.reshape(b, h, cfg.ssm_head_dim), dt, A,
         B.reshape(b, g, n), C.reshape(b, g, n), cache["ssm"],
     )
     y = y + p["D_skip"][None, :, None] * xs.reshape(b, h, cfg.ssm_head_dim).astype(jnp.float32)
-    y = y.reshape(b, 1, di).astype(x.dtype)
+    y = y.reshape(b, 1, di).astype(hn.dtype)
     y = rmsnorm_gated(y, z, p["norm_w"], cfg.norm_eps)
     new_cache = {"conv_x": conv_x_in[:, 1:], "conv_BC": conv_BC_in[:, 1:],
                  "ssm": state}
-    return x + y @ p["out_proj"], new_cache
+    return y @ p["out_proj"], new_cache
 
 
 # --------------------------------------------------------------------------- #

@@ -1,11 +1,19 @@
-"""Mixture-of-Experts MLP block (GShard/Switch-style capacity dispatch).
+"""Mixture-of-Experts MLP blocks.
 
-TPU-native formulation: routing is expressed as dense one-hot
-dispatch/combine einsums over an ``(experts, capacity)`` buffer, so under
-GSPMD the token→expert shuffle lowers to a single pair of all-to-alls on the
-``ep``-sharded expert axis (no scatter/gather emulation, no dynamic shapes).
-Dropped tokens (over capacity) fall through the residual connection, standard
-for capacity-factor routing.
+``moe_apply`` (GShard/Switch-style capacity dispatch): routing is expressed
+as dense one-hot dispatch/combine einsums over an ``(experts, capacity)``
+buffer, so under GSPMD the token→expert shuffle lowers to a single pair of
+all-to-alls on the ``ep``-sharded expert axis (no scatter/gather emulation,
+no dynamic shapes).  Dropped tokens (over capacity) fall through the
+residual connection, standard for capacity-factor routing.
+
+``held_moe_apply`` is the expert-parallel shard of a dropless MoE: the
+router spans all ``num_experts``, this chip holds ``experts_held`` of them
+from ``expert_offset``, and it computes only their part of the output for
+the tokens routed to them.  Its dispatch sorts the (token, expert) pairs by
+held expert and runs each expert's rows as one group of a grouped matmul
+(``jax.lax.ragged_dot``), so its memory grows with the routed pairs, not
+with tokens x capacity.
 """
 from __future__ import annotations
 
@@ -90,3 +98,47 @@ def moe_apply(p: Params, x: jax.Array, cfg: ModelConfig
 
     out = jnp.einsum("tkec,ecd->td", comb.astype(x.dtype), eout)
     return out.reshape(b, s, D), aux_loss
+
+
+def held_moe_init(key, cfg: ModelConfig, dtype) -> Params:
+    """A router over all ``num_experts`` and the held experts' SwiGLUs."""
+    D, F, E, H = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.held_experts
+    ks = jax.random.split(key, 4)
+    return {
+        "router": truncated_normal_init(ks[0], (D, E), jnp.float32),
+        "w_gate": truncated_normal_init(ks[1], (H, D, F), dtype),
+        "w_up": truncated_normal_init(ks[2], (H, D, F), dtype),
+        "w_down": truncated_normal_init(
+            ks[3], (H, F, D), dtype, 0.02 / (2 * cfg.num_layers) ** 0.5),
+    }
+
+
+def held_moe_apply(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The held experts' part of a top-k MoE over x (b, s, d): the gates
+    are the softmax of each token's top ``experts_per_token`` router logits
+    over all experts; no token is dropped."""
+    b, s, D = x.shape
+    K, H, o = cfg.experts_per_token, cfg.held_experts, cfg.expert_offset
+    T = b * s
+    xt = x.reshape(T, D)
+    top, idx = jax.lax.top_k(xt.astype(jnp.float32) @ p["router"], K)
+    gates = jax.nn.softmax(top, axis=-1).reshape(-1)  # (T*K,) token-major
+    local = (idx - o).reshape(-1)
+    key = jnp.where((local >= 0) & (local < H), local, H)
+    # A token takes each expert once, so at most min(K, H) of its pairs are
+    # held: the first T * min(K, H) pairs in held-expert order hold them all.
+    order = jnp.argsort(key, stable=True)[: T * min(K, H)]
+    held = key[order] < H
+    sizes = jnp.bincount(key, length=H + 1)[:H].astype(jnp.int32)
+    tok = order // K
+    rows = xt[tok]
+    gate = jax.lax.ragged_dot(rows, p["w_gate"], sizes,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(rows, p["w_up"], sizes,
+                            preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = jax.lax.ragged_dot(h, p["w_down"], sizes,
+                             preferred_element_type=jnp.float32)
+    out = jnp.where(held[:, None], out * gates[order][:, None], 0.0)
+    y = jnp.zeros((T, D), jnp.float32).at[tok].add(out)
+    return y.reshape(b, s, D).astype(x.dtype)

@@ -10,6 +10,8 @@ TPU library.  The kernels' ``impl="pallas"`` entry points raise off a TPU,
 so each test steers ``repro.kernels.on_tpu`` to take the chip's branch.
 """
 import dataclasses
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +24,11 @@ from repro.core.serving import arena_decode, init_arena, init_params
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.fed_reduce.ops import fed_reduce
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.ssd_scan.ops import ssd_decode
 
 GRANITE = get_config("granite_moe_3b_a800m")
+GRANITE4H = get_config("granite_4_0_h_small")
+METRICS = pathlib.Path(__file__).resolve().parents[1] / "bench" / "metrics"
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +113,45 @@ def test_arena_decode_step_compiles_at_granite_widths(chip):
     _assert_kernel(
         lambda p, t, a, ar: arena_decode(p, t, a, ar, cfg, attn_impl="pallas"),
         params, chip((slots,), jnp.int32), chip((slots,), jnp.bool_), arena)
+
+
+def test_ssd_decode_compiles_at_granite4h_widths(chip):
+    """128 slots' state update: 128 heads of 64, d_state 128, f32 state in
+    the folded layout, donated and aliased."""
+    b, h, p, n = 128, GRANITE4H.ssm_heads, GRANITE4H.ssm_head_dim, 128
+    compiled = _assert_kernel(
+        lambda x, dt, A, B, C, s, a: ssd_decode(x, dt, A, B, C, s, a,
+                                                impl="pallas"),
+        chip((b, h, p), jnp.bfloat16), chip((b, h), jnp.float32),
+        chip((h,), jnp.float32), chip((b, 1, n), jnp.bfloat16),
+        chip((b, 1, n), jnp.bfloat16),
+        chip((b, h // 2, n, 2 * p), jnp.float32), chip((b,), jnp.bool_))
+    assert "ssd_decode" in compiled.as_text()
+
+
+def test_hybrid_arena_decode_step_compiles_at_granite4h_widths(chip):
+    """One granite-4.0-h-small decode step, published widths cut to a Mamba
+    and an attention layer with 9 of 72 experts held: both decode kernels,
+    the held-expert grouped matmuls and the arena's two kinds of state."""
+    cfg = dataclasses.replace(GRANITE4H, num_layers=2,
+                              layer_types=("mamba", "attention"),
+                              experts_held=9, ssm_decode_impl="pallas")
+    slots, max_len = 16, 161
+    as_chip = lambda tree: jax.tree.map(lambda a: chip(a.shape, a.dtype),
+                                        tree)
+    params = as_chip(jax.eval_shape(lambda: init_params(cfg, 0)))
+    arena = as_chip(jax.eval_shape(lambda: init_arena(cfg, slots, max_len)))
+    text = _assert_kernel(
+        lambda p, t, a, ar: arena_decode(p, t, a, ar, cfg, attn_impl="pallas"),
+        params, chip((slots,), jnp.int32), chip((slots,), jnp.bool_),
+        arena).as_text()
+    assert "decode_attention" in text
+    # The benchmark's readers find the state update by the name a trace
+    # gives its instruction (the part before " = "), with or without "%".
+    names = re.findall(r"^\s*(?:ROOT\s+)?(%ssd_decode\S*) = ", text, re.M)
+    assert len(names) == 1  # one Mamba layer
+    for metric in ("ssd_decode_roofline", "hybrid_decode_step_ms"):
+        src = (METRICS / f"{metric}.py").read_text()
+        pattern = re.search(r'^KERNEL = r"(.*)"', src, re.M).group(1)
+        assert all(re.search(pattern, n) and re.search(pattern, n[1:])
+                   for n in names), (metric, names)

@@ -69,7 +69,8 @@ def test_smoke_logits_shape(arch):
 
 
 @pytest.mark.parametrize("arch", ["phi3_medium_14b", "mamba2_1_3b",
-                                  "zamba2_1_2b", "granite_moe_3b_a800m"])
+                                  "zamba2_1_2b", "granite_moe_3b_a800m",
+                                  "granite_4_0_h_small"])
 def test_prefill_decode_matches_full_forward(arch):
     """Greedy continuation via prefill+decode equals full-sequence forward."""
     cfg = get_config(arch, smoke=True)
@@ -117,6 +118,7 @@ def test_param_counts_match_targets():
         "zamba2_1_2b": (1.0e9, 1.4e9),
         "mamba2_1_3b": (1.2e9, 1.6e9),
         "granite_moe_3b_a800m": (3.0e9, 3.8e9),
+        "granite_4_0_h_small": (31e9, 33e9),  # "32B-A9B"
         "phi3_5_moe_42b_a6_6b": (40e9, 44e9),
     }
     for arch, (lo, hi) in targets.items():
@@ -124,7 +126,10 @@ def test_param_counts_match_targets():
         assert lo <= n <= hi, (arch, n)
 
 
-def test_moe_active_params_fraction():
-    cfg = get_config("phi3_5_moe_42b_a6_6b")
-    act = cfg.active_params()
-    assert 5e9 <= act <= 9e9  # "a6.6b"
+@pytest.mark.parametrize("arch,lo,hi", [
+    ("phi3_5_moe_42b_a6_6b", 5e9, 9e9),  # "a6.6b"
+    ("granite_4_0_h_small", 8e9, 10e9),  # "A9B"
+])
+def test_moe_active_params_fraction(arch, lo, hi):
+    act = get_config(arch).active_params()
+    assert lo <= act <= hi
